@@ -40,7 +40,7 @@ try:
 except ImportError:                     # script mode: python benchmarks/...
     from _bench import read_bench, write_bench
 
-from repro.core.cost import HW          # shared with the floorplanner
+from repro.core.cost import HW, V5E     # shared with the floorplanner
 
 _CODE_SALT = None
 
@@ -119,9 +119,9 @@ def _measure_variant(cfg, shape, mesh, *, pol=None, scan_layers=True,
             per = (u4[k] - u2[k]) / 2.0
             full[k] = max(u2[k] - 2 * per + L * per, 0.0)
     return {
-        "compute_s": full["flops"] / HW["peak_flops"],
-        "memory_s": full["bytes"] / HW["hbm_bw"],
-        "collective_s": full["coll"] / HW["ici_bw"],
+        "compute_s": full["flops"] / HW[V5E]["peak_flops"],
+        "memory_s": full["bytes"] / HW[V5E]["hbm_bw"],
+        "collective_s": full["coll"] / HW[V5E]["ici_bw"],
         "hbm_per_dev_gb": (m_scan["arg_bytes"] + m_scan["temp_bytes"]) / 1e9,
         "raw": full,
     }
@@ -315,8 +315,8 @@ def run_cell(name: str, builder, memo: bool = True) -> dict:
                 attn_bytes = L * tloc * hd * (2 * nh + 2 * nkv) * 2 * 3
                 attn_flops = (L * 3 * 0.5 * 2 * 2
                               * tloc * shape.seq_len * nh * hd)
-                t["memory_s"] += attn_bytes / HW["hbm_bw"]
-                t["compute_s"] += attn_flops / HW["peak_flops"]
+                t["memory_s"] += attn_bytes / HW[V5E]["hbm_bw"]
+                t["compute_s"] += attn_flops / HW[V5E]["peak_flops"]
                 t["analytic_attn"] = {"bytes": attn_bytes,
                                       "flops": attn_flops}
             row = {"variant": v["name"], "hypothesis": v["hypothesis"],

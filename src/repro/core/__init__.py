@@ -29,7 +29,7 @@ from .interface import (AsyncMMap, Interface, InterfaceBinding, MMap,
 from .invoke import invoke
 from .synth import (CompiledEngine, StepTask,   # registers ENGINES["compiled"]
                     elaborate_step_graph)
-from .cost import HW, probe_compiled, task_cost
+from .cost import HW, hw_peaks, probe_compiled, task_cost
 from .floorplan import Placement, placement_key, plan_placement
 from .task import TaskBuilder, TaskInstance, task
 
@@ -51,6 +51,6 @@ __all__ = [
     "async_mmap", "mmap", "scalar",
     "ChannelInfo", "CompiledEngine", "StepTask", "SynthesisError",
     "CrashFault", "elaborate_step_graph",
-    "HW", "probe_compiled", "task_cost",
+    "HW", "hw_peaks", "probe_compiled", "task_cost",
     "Placement", "placement_key", "plan_placement",
 ]

@@ -309,13 +309,21 @@ def runtime_value(v: Any) -> Any:
     return v
 
 
+def toolchain_tag() -> str:
+    """Backend plus device kind (``tpu/TPU v5 lite``): what an executable
+    was compiled for, folded into every compile key."""
+    import jax
+    return f"{jax.default_backend()}/{jax.devices()[0].device_kind}"
+
+
 def instance_key(fn: Callable, args: tuple = (), kwargs: Optional[dict] = None,
                  *, extra: Any = None, digest: Optional[str] = None) -> str:
     """Full cache key: definition digest + aval signature + toolchain.
 
     Executables are only valid for (definition, input avals, jax version,
-    backend); all four are folded into the key so a toolchain upgrade or a
-    backend switch is a clean miss, never a wrong hit.  ``digest``: a
+    device kind); all four are folded into the key so a toolchain upgrade,
+    a backend switch or another TPU generation is a clean miss, never a
+    wrong hit.  ``digest``: a
     precomputed ``structural_digest(fn)`` — callers keying many instances
     of one definition pass it to skip the redundant content hash.
     """
@@ -323,8 +331,7 @@ def instance_key(fn: Callable, args: tuple = (), kwargs: Optional[dict] = None,
     h = hashlib.sha256()
     h.update((digest or structural_digest(fn)).encode())
     h.update(_stable_repr(aval_signature(args, kwargs or {})).encode())
-    h.update(f"jax:{jax.__version__}:{jax.default_backend()}:{SCHEMA}"
-             .encode())
+    h.update(f"jax:{jax.__version__}:{toolchain_tag()}:{SCHEMA}".encode())
     if extra is not None:
         _enc(h, extra)
     return h.hexdigest()
@@ -343,17 +350,53 @@ class CacheStats:
     evictions: int = 0
     corrupt: int = 0
     serialize_failures: int = 0
+    disk_writes: int = 0            # executables that reached disk
     memo_hits: int = 0
 
     def as_dict(self) -> dict:
         return dict(self.__dict__)
 
 
-def _default_root() -> Path:
-    return Path(os.environ.get(
-        "REPRO_COMPILE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "repro-compile-cache")))
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed in-checkout default (listed in .gitignore): the path is part of
+# what makes a cache hit, so it never depends on the process or the time
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".cache" / "jax"
+
+
+def cache_root() -> Path:
+    """Directory of JAX's persistent compilation cache:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``.cache/jax`` in the
+    checkout.  This repo's executable store lives in its ``repro/``
+    subdirectory, so one variable places both caches."""
+    env = os.environ.get(CACHE_ENV)
+    return Path(env) if env else _CHECKOUT_CACHE
+
+
+def enable_persistent_cache() -> Path:
+    """Turn on JAX's persistent compilation cache at :func:`cache_root`.
+
+    With ``$JAX_COMPILATION_CACHE_DIR`` set JAX already reads it, and
+    nothing is changed.  Process entry points (the ``launch`` CLIs,
+    ``chip_smoke.py``) call this before their first compile, so a warm
+    process skips XLA for jitted steps that do not go through
+    :class:`CompileCache` (the training step); library functions leave
+    JAX's global configuration alone."""
+    import jax
+    root = cache_root()
+    if os.environ.get(CACHE_ENV) is None and \
+            jax.config.jax_compilation_cache_dir != str(root):
+        from jax.experimental.compilation_cache import compilation_cache
+        jax.config.update("jax_compilation_cache_dir", str(root))
+        compilation_cache.reset_cache()
+    return root
+
+
+def _devices(ids: list) -> list:
+    """Visible devices by id, in the given order (KeyError when one is
+    missing: the entry was built for devices this process cannot see)."""
+    import jax
+    by_id = {d.id: d for d in jax.devices()}
+    return [by_id[i] for i in ids]
 
 
 # Framed executable entries: magic + sha256(blob) + blob.  The digest makes
@@ -396,7 +439,8 @@ class CompileCache:
     def __init__(self, root: Optional[os.PathLike] = None,
                  max_bytes: int = 512 << 20, disk: bool = True,
                  faults: Any = None):
-        self.root = Path(root) if root is not None else _default_root()
+        self.root = Path(root) if root is not None \
+            else cache_root() / "repro"
         self.max_bytes = max_bytes
         self.disk = disk
         # chaos harness (repro.core.faults): injected transient write
@@ -457,7 +501,9 @@ class CompileCache:
                     if entry.get("schema") != SCHEMA:
                         raise ValueError("schema mismatch")
                     payload, in_tree, out_tree = entry["payload"]
-                    exe = se.deserialize_and_load(payload, in_tree, out_tree)
+                    exe = se.deserialize_and_load(
+                        payload, in_tree, out_tree,
+                        execution_devices=_devices(entry["devices"]))
                     os.utime(p)                       # LRU bump
                     with self._lock:
                         self._mem[key] = exe
@@ -485,9 +531,13 @@ class CompileCache:
         try:
             from jax.experimental import serialize_executable as se
             payload = se.serialize(executable)
+            # the devices it runs on, in order: deserializing without them
+            # would spread a one-device program over every visible device
+            devices = [d.id for d in
+                       executable.runtime_executable().local_devices()]
             buf = io.BytesIO()
-            pickle.dump({"schema": SCHEMA, "key": key,
-                         "meta": meta or {}, "payload": payload}, buf)
+            pickle.dump({"schema": SCHEMA, "key": key, "meta": meta or {},
+                         "devices": devices, "payload": payload}, buf)
         except Exception:
             # not every executable serializes (callbacks, exotic custom
             # calls); stay memory-only rather than fail the compile
@@ -495,9 +545,11 @@ class CompileCache:
                 self.stats.serialize_failures += 1
             return
         path = self._path(key)
-        if self._write_atomic(path, _frame(buf.getvalue()), verify=True) and \
-                self.faults is not None and self.faults.corrupt_cache():
-            self._corrupt_entry(path)   # chaos: prove delete+recompile works
+        if self._write_atomic(path, _frame(buf.getvalue()), verify=True):
+            with self._lock:
+                self.stats.disk_writes += 1
+            if self.faults is not None and self.faults.corrupt_cache():
+                self._corrupt_entry(path)   # chaos: prove delete+recompile
         self._maybe_evict()
 
     def compile_cached(self, fn: Callable, args: tuple = (),
@@ -656,8 +708,7 @@ _default_lock = threading.Lock()
 
 
 def default_cache() -> CompileCache:
-    """Process-wide cache; root from ``$REPRO_COMPILE_CACHE`` (or
-    ``~/.cache/repro-compile-cache``), bound from
+    """Process-wide cache under ``cache_root() / "repro"``, bound from
     ``$REPRO_COMPILE_CACHE_MAX_MB`` (default 512)."""
     global _default
     with _default_lock:

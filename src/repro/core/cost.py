@@ -39,13 +39,32 @@ from .synth import (_ChanRef, _MMapRef, _PortRef, _canon_dtype, _chan_specs,
 
 COST_SCHEMA = "cost1"
 
-# Reference hardware terms (one TPU-class chip + ICI link): the floorplan
-# objective and perf_iter's fit-corrected terms both convert raw counters
-# into seconds with these, so "compute seconds" and "cut-traffic seconds"
-# are commensurable.  Placement decisions only use ratios, so the exact
-# numbers matter less than their being shared.
-HW = {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
-      "hbm_capacity": 16e9}
+# Per-chip peaks keyed by ``device_kind``: the floorplan objective and
+# perf_iter's fit-corrected terms convert raw counters into seconds with
+# these, so "compute seconds" and "cut-traffic seconds" are commensurable.
+V5E = "TPU v5 lite"
+HW = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+    V5E: {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 1600e9 / 8,
+          "hbm_capacity": 16e9},
+    # nominal, for tests on the CPU backend only: not a measured machine
+    "cpu": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
+            "hbm_capacity": 16e9},
+}
+
+
+def hw_peaks(device_kind: Optional[str] = None) -> dict:
+    """The :data:`HW` row for ``device_kind`` (default: the first visible
+    device).  A kind with no row is an error, never another chip's
+    numbers."""
+    kind = device_kind or jax.devices()[0].device_kind
+    try:
+        return HW[kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {kind!r}; add its "
+                       f"published peaks to repro.core.cost.HW") from None
+
 
 # in-process cost cells (the disk memo's L1): probe key -> result dict
 _CELLS: dict[str, dict] = {}
@@ -56,21 +75,11 @@ def clear_cost_cells() -> None:
     _CELLS.clear()
 
 
-def _normalize_cost(cost: Any) -> dict:
-    """``cost_analysis`` returns a dict, or a per-device list on some
-    jax versions, or None when the backend offers nothing."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def _extract_compiled(compiled) -> dict:
     from ..launch.dryrun import collective_bytes   # lazy: launch is heavy
-    cost = _normalize_cost(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
-    if isinstance(mem, (list, tuple)):
-        mem = mem[0] if mem else None
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
             "coll": float(coll["total_bytes"]),
@@ -173,9 +182,7 @@ def phase_cost(plan, tp, ph, *, cache: Any = None) -> dict:
     low = jax.jit(probe).lower(_state_spec(tp.state0),
                                _chan_specs(plan, tp),
                                _mmap_specs(plan, tp))
-    cost = _normalize_cost(low.cost_analysis())
-    if not cost:                        # backend offered nothing lowered:
-        cost = _normalize_cost(low.compile().cost_analysis())
+    cost = low.cost_analysis() or low.compile().cost_analysis() or {}
     out = {"flops": float(cost.get("flops", 0.0)),
            "bytes": float(cost.get("bytes accessed", 0.0)),
            # a single step firing is device-local by construction; the
@@ -193,7 +200,7 @@ def task_cost(plan, tp, *, cache: Any = None, hw: Optional[dict] = None
     """Whole-budget cost of one task instance: per-phase firing cost x
     firing count, plus the roofline-converted ``seconds`` the floorplan
     objective balances."""
-    hw = hw or HW
+    hw = hw or hw_peaks()
     tot = {"flops": 0.0, "bytes": 0.0, "coll": 0.0}
     per_phase = []
     for ph in tp.phases:

@@ -41,7 +41,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from .compile_cache import _stable_repr, default_cache
-from .cost import HW, task_cost
+from .cost import hw_peaks, task_cost
 from .errors import SynthesisError
 from .synth import _canon_dtype
 
@@ -71,14 +71,17 @@ class Placement:
 
 
 def placement_key(graph_hash: str, n_devices: int,
-                  overrides: Optional[dict] = None) -> str:
+                  overrides: Optional[dict] = None,
+                  hw: Optional[dict] = None) -> str:
     """Content address of a placement artifact: graph structure + mesh
-    width + manual pins + schema. Same inputs ⇒ byte-identical artifact
-    in any process."""
+    width + manual pins + the hardware peaks that priced it (default:
+    the visible device's row) + schema. Same inputs ⇒ byte-identical
+    artifact in any process."""
     h = hashlib.sha256()
     h.update(f"floorplan:{FLOORPLAN_SCHEMA}:{graph_hash}:"
              f"dev={int(n_devices)}:".encode())
     h.update(_stable_repr(tuple(sorted((overrides or {}).items()))).encode())
+    h.update(_stable_repr(sorted((hw or hw_peaks()).items())).encode())
     return "place_" + h.hexdigest()
 
 
@@ -164,7 +167,7 @@ def plan_placement(plan, graph, n_devices: int, *,
     memoizes the artifact in the process compile cache, ``cache=False``
     disables memoization.
     """
-    hw = hw or HW
+    hw = hw or hw_peaks()
     n_devices = int(n_devices)
     if n_devices < 1:
         raise SynthesisError(f"cannot floorplan onto {n_devices} devices")
@@ -173,7 +176,7 @@ def plan_placement(plan, graph, n_devices: int, *,
     _validate_overrides(names, overrides, n_devices)
 
     cc = default_cache() if cache is None else (cache or None)
-    key = placement_key(graph.structural_hash(), n_devices, overrides)
+    key = placement_key(graph.structural_hash(), n_devices, overrides, hw)
     if cc is not None:
         hit = cc.memo_get(key)
         if (hit is not None and hit.get("version") == FLOORPLAN_SCHEMA

@@ -80,7 +80,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .channel import Channel, IStream, OStream
-from .compile_cache import aval_signature, default_cache, _stable_repr
+from .compile_cache import (_stable_repr, aval_signature, default_cache,
+                            toolchain_tag)
 from .context import clear_context, set_context
 from .engines import ENGINES, EngineBase, SimReport
 from .errors import (ChannelMisuse, DeadlockReport, GraphValidationError,
@@ -94,15 +95,6 @@ from ..kernels.ring import (RING_CHOICES, RING_ENV, eval_guards, ring_pop,
                             ring_push)
 
 SYNTH_SCHEMA = "synth3"
-
-try:                                    # moved to jax.shard_map in 0.5+
-    _shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
 
 
 def _canon_dtype(dtype: Any) -> np.dtype:
@@ -1339,9 +1331,9 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
     from jax.sharding import PartitionSpec as _P
 
     def program(states0: tuple, mmaps0: tuple):
-        return _shard_map(device_body, mesh=mesh,
-                          in_specs=(_P(), _P()), out_specs=_P(axis),
-                          check_vma=False)(states0, mmaps0)
+        return jax.shard_map(device_body, mesh=mesh,
+                             in_specs=(_P(), _P()), out_specs=_P(axis),
+                             check_vma=False)(states0, mmaps0)
 
     return program
 
@@ -1387,6 +1379,8 @@ class CompiledEngine(EngineBase):
         # post-run introspection (tests / benchmarks)
         self.compile_source: Optional[str] = None
         self.compile_key: Optional[str] = None
+        self.compile_s = 0.0            # executable resolve (compile/load)
+        self.ring_impl_used: Optional[str] = None
         self.n_sweeps = 0
         self.placement_used = None      # floorplan.Placement after a run
         self.partition_source = None    # "partitioned" | "memo" | None
@@ -1476,6 +1470,7 @@ class CompiledEngine(EngineBase):
         plan.ring_impl = resolve_impl("ring", RING_ENV, RING_CHOICES,
                                       fallback="xla",
                                       impl=getattr(self, "ring_impl", None))
+        self.ring_impl_used = plan.ring_impl
         bound = []
         for inst in step_insts:
             a, k = bind_streams(inst)
@@ -1566,7 +1561,7 @@ class CompiledEngine(EngineBase):
         h = hashlib.sha256()
         h.update(graph.structural_hash().encode())
         h.update(_stable_repr(aval_signature(args, {})).encode())
-        h.update(f"jax:{jax.__version__}:{jax.default_backend()}:"
+        h.update(f"jax:{jax.__version__}:{toolchain_tag()}:"
                  f"{SYNTH_SCHEMA}:ring={ring_impl}:{extra}".encode())
         return h.hexdigest()
 
@@ -1597,17 +1592,7 @@ class CompiledEngine(EngineBase):
             program = _build_program(plan)
             key = self._cache_key(graph, (states0, mmaps0, ports0),
                                   plan.ring_impl)
-            self.compile_key = key
-            if self.cache is False:
-                exe = jax.jit(program).lower(
-                    states0, mmaps0, ports0).compile()
-                source = "compiled"
-            else:
-                cc = self.cache if self.cache is not None \
-                    else default_cache()
-                exe, source = cc.compile_cached(
-                    program, (states0, mmaps0, ports0), key=key)
-            self.compile_source = source
+            exe = self._resolve(program, (states0, mmaps0, ports0), key)
             mm_final, ports_final, fires, sweeps, maxocc, sizes = exe(
                 states0, mmaps0, ports0)
             self._writeback_ports(plan, ports_final)
@@ -1616,6 +1601,22 @@ class CompiledEngine(EngineBase):
                                 sizes, result, t0)
         finally:
             clear_context()
+
+    def _resolve(self, program: Callable, args: tuple, key: str):
+        """The executable for ``program`` through the compile cache (or a
+        plain compile with ``cache=False``); records its source, key and
+        the seconds it took."""
+        t0 = time.perf_counter()
+        if self.cache is False:
+            exe = jax.jit(program).lower(*args).compile()
+            source = "compiled"
+        else:
+            cc = self.cache if self.cache is not None else default_cache()
+            exe, source = cc.compile_cached(program, args, key=key)
+        self.compile_source = source
+        self.compile_key = key
+        self.compile_s = time.perf_counter() - t0
+        return exe
 
     def _resolve_mesh(self):
         """``self.mesh`` as a validated 1-D Mesh: an int means "the
@@ -1675,15 +1676,7 @@ class CompiledEngine(EngineBase):
         key = self._cache_key(
             graph, (states0, mmaps0), plan.ring_impl,
             extra=f"mesh={axis}:{n_dev}:owners={owners.tolist()}")
-        self.compile_key = key
-        if self.cache is False:
-            exe = jax.jit(program).lower(states0, mmaps0).compile()
-            source = "compiled"
-        else:
-            cc = self.cache if self.cache is not None else default_cache()
-            exe, source = cc.compile_cached(
-                program, (states0, mmaps0), key=key)
-        self.compile_source = source
+        exe = self._resolve(program, (states0, mmaps0), key)
         mm_st, fires_st, sweeps_st, maxocc_st, sizes_st = exe(
             states0, mmaps0)
         # authoritative rows: the writer's owner per written mmap (the

@@ -36,15 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # moved to jax.shard_map in 0.5+
-    _shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
 from ..core import channel, task
 from ..core.engines import ENGINES, SimReport
 
@@ -187,7 +178,7 @@ def compile_pipeline(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
     S = mesh.shape[axis]
     M = microbatches.shape[0]
     pipe = spmd_pipeline(stage_fn, S, M, axis)
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         pipe, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)
@@ -228,7 +219,7 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stacked_params: Any,
         return exe(stacked_params, microbatches)
 
     pipe = spmd_pipeline(stage_fn, S, M, axis)
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         pipe, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)
@@ -250,7 +241,7 @@ def pipeline_loss_fn(mesh: Mesh, stage_fn: Callable, loss_tail: Callable,
             outs = pipe(params, xs)                    # [M, mb, ...]
             return loss_tail(outs, ys)
 
-        shmapped = _shard_map(
+        shmapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(axis), P(), P()),
             out_specs=P(), check_vma=False)
         return shmapped(stacked_params, microbatches, labels)

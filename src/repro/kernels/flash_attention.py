@@ -107,14 +107,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref,          # inputs
         l = l_ref[:, 0]
         safe = jnp.where(l == 0.0, 1.0, l)                  # fully-masked row
         o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(safe)
+        # lane-broadcast (block_q, 128) tile: a (1, block_q) row block
+        # would not tile; the wrapper keeps lane 0
+        lse_ref[0, 0] = m_ref[...] + jnp.log(safe)[:, None]
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool, window: Optional[int],
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = True) -> tuple:
+                        interpret: bool) -> tuple:
     """q: [B, nh, Sq, hd]; k/v: [B, nkv, Sk, hd] (head-major layout).
 
     Returns (out [B, nh, Sq, hd], lse [B, nh, Sq] fp32).
@@ -145,12 +147,12 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, block_q, STATS_LANES),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, nh, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, nh, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, nh, Sq, STATS_LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
@@ -159,4 +161,4 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse[..., 0]

@@ -5,14 +5,13 @@ size)`` carried through a ``lax.while_loop`` — see ``core/synth.py``.
 This module provides the three hot ops of that sweep loop as Pallas
 kernels with a bit-exact XLA reference:
 
-* :func:`ring_pop`   — pop ``n`` tokens: burst slice out of the ring
-  with the head/size update fused into the same op.  The contiguous
-  case (``head + n <= cap``) is ONE VMEM slice copy; the wraparound
-  case splits into per-row copies of the two contiguous segments
-  (the double-buffer halves of a hardware FIFO burst).
+* :func:`ring_pop`   — pop ``n`` tokens: burst copy out of the ring
+  with the head/size update fused into the same op.  The ring stays in
+  HBM; the contiguous case (``head + n <= cap``) is ONE DMA at the
+  dynamic head offset, the wraparound case one DMA per row.
 * :func:`ring_push`  — push ``n`` tokens at ``(head + size) % cap``,
-  same contiguous-fast-path / wrap-split structure, writing through a
-  full-ring VMEM copy so the op stays functional.
+  same contiguous/wrap structure; the output aliases the ring, so only
+  the pushed rows move.
 * :func:`eval_guards` — fused firing-predicate evaluation: ONE kernel
   computes every task's fire guard from the channel occupancy vector
   (``need_r <= size`` and ``need_w <= cap - size`` reduced over the
@@ -36,8 +35,8 @@ bitwise, not approximate.
 
 from __future__ import annotations
 
-from functools import partial, reduce
-from typing import Optional, Sequence
+from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -63,13 +62,6 @@ def _ceil(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _flat(buf: jax.Array) -> tuple[jax.Array, int]:
-    """[cap, *elem] -> [cap, E] (E >= 1) for the 2-D kernels."""
-    cap = buf.shape[0]
-    e = int(np.prod(buf.shape[1:], dtype=np.int64)) if buf.ndim > 1 else 1
-    return buf.reshape(cap, max(e, 1)), max(e, 1)
-
-
 def _kernel_dtype(dtype) -> np.dtype:
     """bools ride the kernels as int32 (TPU vregs have no 1-bit lanes);
     the wrappers cast back, which is exact for {0, 1}."""
@@ -77,22 +69,83 @@ def _kernel_dtype(dtype) -> np.dtype:
     return np.dtype(np.int32) if d == np.bool_ else d
 
 
+def _tiled(x: jax.Array) -> jax.Array:
+    """``[rows, *elem] -> [rows, R, L]`` with ``(R, L)`` aligned to the
+    dtype's HBM tile, so a DMA at any dynamic row offset moves whole
+    tiles.  Elements whose last two dims are already aligned keep them
+    (merging leading dims is then free); anything else is flattened and
+    zero-padded to ``R = sublanes``-multiple rows of 128 lanes."""
+    x = x.astype(_kernel_dtype(x.dtype))
+    rows, elem = x.shape[0], x.shape[1:]
+    sub = _SUB * max(1, 4 // x.dtype.itemsize)      # 8 f32, 16 bf16, 32 i8
+    if len(elem) >= 2 and elem[-1] % _LANE == 0 and elem[-2] % sub == 0:
+        return x.reshape(rows, -1, elem[-1])
+    e = int(np.prod(elem, dtype=np.int64))
+    e_p = _ceil(max(e, 1), sub * _LANE)
+    flat = jnp.pad(x.reshape(rows, e), ((0, 0), (0, e_p - e)))
+    return flat.reshape(rows, e_p // _LANE, _LANE)
+
+
+def _untiled(t: jax.Array, like_shape: tuple, dtype) -> jax.Array:
+    """Inverse of :func:`_tiled` for ``like_shape = (rows, *elem)``."""
+    rows, elem = like_shape[0], like_shape[1:]
+    e = int(np.prod(elem, dtype=np.int64))
+    flat = t.reshape(rows, -1)[:, :e]
+    return flat.reshape(like_shape).astype(dtype)
+
+
+def _ring_call(kernel, out_shape, scalar, *arrays, aliases=None,
+               interpret: bool):
+    """One-step ``pallas_call`` with every array left in HBM
+    (``pl.ANY``): the kernel moves rows by DMA at the dynamic offset in
+    ``scalar`` (scalar-prefetched), so no VMEM block ever holds the ring."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(arrays),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=out_shape,
+        input_output_aliases=aliases or {},
+        interpret=interpret,
+    )(jnp.asarray(scalar, jnp.int32).reshape(1), *arrays)
+
+
+def _copy_rows(src, dst, src_at, dst_at, n: int, cap: int, wrap_src: bool,
+               sem):
+    """DMA ``n`` ring rows between ``src`` and ``dst``.  The ring side
+    starts at ``src_at`` (pop) or ``dst_at`` (push); the contiguous case
+    is one DMA, the wraparound case one DMA per row."""
+    ring_at = src_at if wrap_src else dst_at
+
+    @pl.when(ring_at + n <= cap)
+    def _contig():
+        cp = pltpu.make_async_copy(src.at[pl.ds(src_at, n)],
+                                   dst.at[pl.ds(dst_at, n)], sem)
+        cp.start()
+        cp.wait()
+
+    @pl.when(ring_at + n > cap)
+    def _wrap():
+        def row(i, carry):
+            idx = jax.lax.rem(ring_at + i, jnp.int32(cap))
+            s_i, d_i = (idx, dst_at + i) if wrap_src else (src_at + i, idx)
+            cp = pltpu.make_async_copy(src.at[pl.ds(s_i, 1)],
+                                       dst.at[pl.ds(d_i, 1)], sem)
+            cp.start()
+            cp.wait()
+            return carry
+        jax.lax.fori_loop(0, n, row, 0)
+
+
 # ---------------------------------------------------------------------------
 # pop
 # ---------------------------------------------------------------------------
 
-def _pop_kernel(n: int, cap: int, s_ref, buf_ref, out_ref):
-    head = s_ref[0]
-
-    @pl.when(head + n <= cap)
-    def _contig():
-        out_ref[pl.ds(0, n), :] = buf_ref[pl.ds(head, n), :]
-
-    @pl.when(head + n > cap)
-    def _wrap():
-        for i in range(n):
-            idx = jax.lax.rem(head + jnp.int32(i), jnp.int32(cap))
-            out_ref[pl.ds(i, 1), :] = buf_ref[pl.ds(idx, 1), :]
+def _pop_kernel(n: int, cap: int, s_ref, buf_ref, out_ref, sem):
+    _copy_rows(buf_ref, out_ref, s_ref[0], 0, n, cap, True, sem)
 
 
 def ring_pop(buf: jax.Array, head: jax.Array, size: jax.Array, n: int, *,
@@ -105,7 +158,6 @@ def ring_pop(buf: jax.Array, head: jax.Array, size: jax.Array, n: int, *,
     """
     impl = _resolve(impl)
     cap = buf.shape[0]
-    elem = buf.shape[1:]
     n = int(n)
     new_head = (head + n) % cap
     new_size = size - n
@@ -114,23 +166,12 @@ def ring_pop(buf: jax.Array, head: jax.Array, size: jax.Array, n: int, *,
     if impl == "xla":
         idx = (head + jnp.arange(n, dtype=jnp.int32)) % cap
         return buf[idx], new_head, new_size
-    flat, e = _flat(buf)
-    kdt = _kernel_dtype(flat.dtype)
-    flat = flat.astype(kdt)
-    cap_p, n_p, e_p = _ceil(cap, _SUB), _ceil(n, _SUB), _ceil(e, _LANE)
-    flat = jnp.pad(flat, ((0, cap_p - cap), (0, e_p - e)))
-    scalars = jnp.asarray(head, jnp.int32).reshape(1)
-    out = pl.pallas_call(
+    tbuf = _tiled(buf)
+    out = _ring_call(
         partial(_pop_kernel, n, cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((cap_p, e_p), lambda i, s: (0, 0))],
-            out_specs=pl.BlockSpec((n_p, e_p), lambda i, s: (0, 0))),
-        out_shape=jax.ShapeDtypeStruct((n_p, e_p), kdt),
-        interpret=impl == "interpret",
-    )(scalars, flat)
-    toks = out[:n, :e].astype(buf.dtype).reshape((n,) + elem)
+        jax.ShapeDtypeStruct((n,) + tbuf.shape[1:], tbuf.dtype),
+        head, tbuf, interpret=impl == "interpret")
+    toks = _untiled(out, (n,) + buf.shape[1:], buf.dtype)
     return toks, new_head, new_size
 
 
@@ -138,19 +179,9 @@ def ring_pop(buf: jax.Array, head: jax.Array, size: jax.Array, n: int, *,
 # push
 # ---------------------------------------------------------------------------
 
-def _push_kernel(n: int, cap: int, s_ref, buf_ref, arr_ref, out_ref):
-    out_ref[...] = buf_ref[...]
-    start = s_ref[0]
-
-    @pl.when(start + n <= cap)
-    def _contig():
-        out_ref[pl.ds(start, n), :] = arr_ref[pl.ds(0, n), :]
-
-    @pl.when(start + n > cap)
-    def _wrap():
-        for i in range(n):
-            idx = jax.lax.rem(start + jnp.int32(i), jnp.int32(cap))
-            out_ref[pl.ds(idx, 1), :] = arr_ref[pl.ds(i, 1), :]
+def _push_kernel(n: int, cap: int, s_ref, buf_ref, arr_ref, out_ref, sem):
+    # ``out_ref`` aliases ``buf_ref``: only the pushed rows are written
+    _copy_rows(arr_ref, out_ref, 0, s_ref[0], n, cap, False, sem)
 
 
 def ring_push(buf: jax.Array, head: jax.Array, size: jax.Array,
@@ -169,28 +200,13 @@ def ring_push(buf: jax.Array, head: jax.Array, size: jax.Array,
     if impl == "xla":
         idx = (head + size + jnp.arange(n, dtype=jnp.int32)) % cap
         return buf.at[idx].set(arr), head, new_size
-    flat, e = _flat(buf)
-    aflat, _ = _flat(arr)
-    kdt = _kernel_dtype(flat.dtype)
-    flat = flat.astype(kdt)
-    aflat = aflat.astype(kdt)
-    cap_p, n_p, e_p = _ceil(cap, _SUB), _ceil(n, _SUB), _ceil(e, _LANE)
-    flat = jnp.pad(flat, ((0, cap_p - cap), (0, e_p - e)))
-    aflat = jnp.pad(aflat, ((0, n_p - n), (0, e_p - e)))
-    start = jnp.asarray((head + size) % cap, jnp.int32).reshape(1)
-    out = pl.pallas_call(
+    tbuf = _tiled(buf)
+    out = _ring_call(
         partial(_push_kernel, n, cap),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((cap_p, e_p), lambda i, s: (0, 0)),
-                      pl.BlockSpec((n_p, e_p), lambda i, s: (0, 0))],
-            out_specs=pl.BlockSpec((cap_p, e_p), lambda i, s: (0, 0))),
-        out_shape=jax.ShapeDtypeStruct((cap_p, e_p), kdt),
-        interpret=impl == "interpret",
-    )(start, flat, aflat)
-    new_buf = out[:cap, :e].astype(buf.dtype).reshape(buf.shape)
-    return new_buf, head, new_size
+        jax.ShapeDtypeStruct(tbuf.shape, tbuf.dtype),
+        (head + size) % cap, tbuf, _tiled(arr.astype(buf.dtype)),
+        aliases={1: 0}, interpret=impl == "interpret")
+    return _untiled(out, buf.shape, buf.dtype), head, new_size
 
 
 # ---------------------------------------------------------------------------

@@ -56,13 +56,23 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, s0_ref,   # inputs
     Bm = b_ref[0, 0].astype(jnp.float32)          # [Q, N]
     Cm = c_ref[0, 0].astype(jnp.float32)          # [Q, N]
 
-    cum = jnp.cumsum(dA[0])                       # [Q] inclusive
+    # inclusive prefix sums of dA as a lower-triangular matmul (Mosaic has
+    # no cumsum lowering): cum_c[i] = cum_r[i] = sum_{j <= i} dA[j]
+    tril = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    trilf = tril.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum_c = jax.lax.dot_general(trilf, dA, (((1,), (1,)), ((), ())),
+                                precision=hi,
+                                preferred_element_type=jnp.float32)  # [Q, 1]
+    cum_r = jax.lax.dot_general(dA, trilf, (((1,), (1,)), ((), ())),
+                                precision=hi,
+                                preferred_element_type=jnp.float32)  # [1, Q]
+    cum_end = jnp.sum(dA)
     # Intra-chunk decay factors decay[i,j] = exp(cum_i - cum_j), j <= i.
     # Mask the exponent (not the exp) so masked entries are exactly 0 and
     # no inf/NaN can leak through.
-    diff = cum[:, None] - cum[None, :]
-    tril = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    diff = cum_c - cum_r
     decay = jnp.exp(jnp.where(tril, diff, -jnp.inf))        # [Q, Q]
 
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
@@ -71,15 +81,15 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, s0_ref,   # inputs
                             preferred_element_type=jnp.float32)   # [Q, P]
 
     # inter-chunk read: y[i] += (C_i * exp(cum_i)) @ state   ([Q,N]@[N,P])
-    head = jnp.exp(cum)[:, None]                             # [Q, 1]
+    head = jnp.exp(cum_c)                                    # [Q, 1]
     y += jax.lax.dot_general(Cm * head, state_ref[...],
                              (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # inter-chunk write: state = exp(cum_end)*state + (tail·xdt)ᵀ B
-    tail = jnp.exp(cum[-1] - cum)[:, None]                   # [Q, 1]
-    new_state = state_ref[...] * jnp.exp(cum[-1]) + jax.lax.dot_general(
+    tail = jnp.exp(cum_end - cum_c)                          # [Q, 1]
+    new_state = state_ref[...] * jnp.exp(cum_end) + jax.lax.dot_general(
         xdt * tail, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                  # [P, N]
     state_ref[...] = new_state
@@ -91,7 +101,7 @@ def _ssd_kernel(xdt_ref, dA_ref, b_ref, c_ref, s0_ref,   # inputs
 
 def ssd_scan_fwd(xdt: jax.Array, dA: jax.Array, Bm: jax.Array,
                  Cm: jax.Array, s0: jax.Array, *, chunk: int,
-                 interpret: bool = True) -> tuple:
+                 interpret: bool) -> tuple:
     """Head-major kernel entry.
 
     xdt: [B, H, S, P] (dt-weighted inputs); dA: [B, H, 1, S];

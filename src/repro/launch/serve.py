@@ -9,19 +9,23 @@ compute inside is the batched packed-slot decode of the selected model:
 one jitted step per iteration for every slot, on-device sampling, and
 length-bucketed prefill AOT-resolved through the persistent compile cache
 (``--per-slot`` selects the seed per-slot path instead; recurrent
-families fall back to it automatically).
+families, which the batched adapter refuses, use it automatically).
+A batched warmup that fails is an error, never a silent per-slot run.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
+from typing import Any
 
 import jax
 import numpy as np
 
 from ..configs import get_config
+from ..core.compile_cache import enable_persistent_cache
 from ..ft import PreemptionGuard
 from ..models import lm
 from ..serve import (AdmissionConfig, AdmissionController, Request,
@@ -72,7 +76,27 @@ def _print_warmup(engine: ServingEngine, info: dict) -> None:
               f"decode={info['decode']}")
 
 
+@dataclasses.dataclass
+class ServeRun:
+    """What one serving invocation did: its exit code and what a caller
+    checks (``chip_smoke.py`` compares the tokens against a direct
+    decode loop with the same ``params``)."""
+    rc: int
+    cfg: Any
+    params: Any
+    engine: ServingEngine
+    requests: list
+    results: dict
+    lazy: list          # (kind, shape) compiled after warmup
+    wall_s: float
+    n_tokens: int
+
+
 def serve(argv=None) -> int:
+    return run_serve(argv).rc
+
+
+def run_serve(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -81,6 +105,9 @@ def serve(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--prefill-buckets", default="",
+                    help="comma-separated prompt buckets to warm and pad "
+                         "to (default: powers of two from 8 to --max-seq)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--per-slot", action="store_true",
                     help="seed path: one decode call per slot per token")
@@ -123,7 +150,9 @@ def serve(argv=None) -> int:
           f"params={cfg.param_count()/1e6:.1f}M slots={args.slots}")
 
     params = lm.init_params(cfg, jax.random.key(args.seed))
-    scfg = ServeConfig(batch_slots=args.slots, max_seq=args.max_seq)
+    buckets = tuple(int(b) for b in args.prefill_buckets.split(",") if b)
+    scfg = ServeConfig(batch_slots=args.slots, max_seq=args.max_seq,
+                       prefill_buckets=buckets)
     engine = _build_engine(cfg, params, scfg, args)
 
     t0 = time.perf_counter()
@@ -136,12 +165,10 @@ def serve(argv=None) -> int:
                              | {args.slots}))
         info = engine.warmup(batch_sizes=sizes)
         if not info.get("ok"):
-            # a batched adapter has no eager path — serve per-slot instead
-            print(f"[serve] batched warmup failed ({info.get('reason')}); "
-                  f"falling back to per-slot")
-            args.per_slot = True
-            engine = _build_engine(cfg, params, scfg, args)
-            info = engine.warmup()
+            # a batched adapter has no eager path, and a per-slot rerun
+            # would hide why the device refused the batched program
+            raise RuntimeError(
+                f"batched warmup failed: {info.get('reason')}")
     else:
         info = engine.warmup()
     warm = time.perf_counter() - t0
@@ -248,13 +275,20 @@ def serve(argv=None) -> int:
                   f"p99={_ms(row['ttft_p99_s'])}")
         # open-loop contract: every offered request gets an answer —
         # tokens or a structured error — never a silent absence
-        return 0 if len(results) == len(reqs) else 1
-    # a preempted run that answered every request (some with structured
-    # rejections) still exits clean — that is the graceful-drain contract
-    if guard.requested:
-        return 0 if len(results) == args.requests else 1
-    return 0 if len(ok) == args.requests else 1
+        ok_run = len(results) == len(reqs)
+    elif guard.requested:
+        # a preempted run that answered every request (some with
+        # structured rejections) still exits clean — that is the
+        # graceful-drain contract
+        ok_run = len(results) == args.requests
+    else:
+        ok_run = len(ok) == args.requests
+    return ServeRun(rc=0 if ok_run else 1, cfg=cfg, params=params,
+                    engine=engine, requests=reqs, results=results,
+                    lazy=[(k, sh) for k, sh, _ in lazy], wall_s=wall,
+                    n_tokens=n_new)
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     sys.exit(serve())

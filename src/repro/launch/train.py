@@ -11,8 +11,9 @@ checkpoint if one exists, so preempted jobs just re-run the same command),
 preemption guard, straggler detection and optional int8 gradient
 compression.
 
-On this CPU container the default is a reduced config; the full configs
-are exercised by the dry-run (launch/dryrun.py).
+The default is the full published config (``--reduced`` for a tiny
+same-family one); ``chip_smoke.py`` runs three full-width qwen3-0.6b
+steps on one TPU.
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ckpt import CheckpointManager
 from ..configs import get_config
+from ..core.compile_cache import enable_persistent_cache
 from ..data import make_pipeline
 from ..distributed import sharding as shd
 from ..ft import PreemptionGuard, StragglerDetector, resume_or_init
@@ -162,4 +164,5 @@ def train(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     sys.exit(train())

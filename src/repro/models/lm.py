@@ -748,16 +748,21 @@ def sample_tokens(logits: jax.Array, key: Optional[jax.Array] = None,
 class ServingAdapter:
     """The batched-decode protocol consumed by ``ServingEngine``.
 
-    ``prefill_fn(tokens[B,S], true_len[B], step) -> (first_tok[B], cache)``
-    ``step_fn(tokens[slots], packed, step) -> (next_tok[slots], packed)``
+    ``prefill_fn(params, tokens[B,S], true_len[B], step)
+    -> (first_tok[B], cache)``
+    ``step_fn(params, tokens[slots], packed, step) -> (next_tok[slots],
+    packed)``
     ``write_slot_fn(packed, cache, row, slot) -> packed``
     ``retire_fn(packed, slot) -> packed``
 
     All four are pure jax functions (NOT pre-jitted): the engine compiles
     them through the persistent compile cache so a fresh process resolves
-    every previously-seen shape from disk.  ``step`` is a traced int32
-    scalar (the global step counter) feeding the sampler's fold_in — it
-    does not trigger recompiles.
+    every previously-seen shape from disk.  The weights ride as the
+    ``params`` argument, never as closure constants: an executable is
+    keyed and serialized by their shapes, not their values, and weights
+    of any size stay device buffers.  ``step`` is a traced int32 scalar
+    (the global step counter) feeding the sampler's fold_in — it does not
+    trigger recompiles.
     """
     cfg: ModelConfig
     max_seq: int
@@ -767,6 +772,7 @@ class ServingAdapter:
     retire_fn: Any
     temperature: float = 0.0
     top_k: int = 0
+    params: Params = None
 
     def init_slots(self, slots: int, abstract: bool = False) -> dict:
         return init_packed_cache(self.cfg, slots, self.max_seq,
@@ -793,12 +799,12 @@ def serving_adapter(params: Params, cfg: ModelConfig, *, max_seq: int,
         key = jax.random.fold_in(base_key, step)
         return sample_tokens(logits, key, temperature, top_k)
 
-    def prefill_fn(tokens, true_len, step):
+    def prefill_fn(params, tokens, true_len, step):
         logits, cache = prefill(params, cfg, tokens, max_seq=max_seq,
                                 true_len=true_len, scan_layers=scan_layers)
         return _sample(logits, step), cache
 
-    def step_fn(tokens, packed, step):
+    def step_fn(params, tokens, packed, step):
         live = packed["len"] > 0
         logits, ncache = decode_step(params, cfg, tokens, packed,
                                      scan_layers=scan_layers)
@@ -811,4 +817,5 @@ def serving_adapter(params: Params, cfg: ModelConfig, *, max_seq: int,
     return ServingAdapter(cfg=cfg, max_seq=max_seq,
                           prefill_fn=prefill_fn, step_fn=step_fn,
                           write_slot_fn=write_slot, retire_fn=retire_slot,
-                          temperature=temperature, top_k=top_k)
+                          temperature=temperature, top_k=top_k,
+                          params=params)

@@ -270,8 +270,8 @@ class ServingEngine:
         toy engines whose step functions are not jittable fall back to
         eager with ``{"ok": False}`` — warmup never breaks per-slot
         serving.  A batched adapter has no eager path: ``{"ok": False}``
-        there means serving itself would fail the same way, so the caller
-        should fall back to a per-slot engine (``launch/serve.py`` does).
+        there means serving itself would fail the same way
+        (``launch/serve.py`` stops with that reason).
         """
         from ..core.compile_cache import aval_signature, default_cache
         cc = cache if cache is not None else default_cache()
@@ -280,10 +280,8 @@ class ServingEngine:
             if rep.get("ok") or self.prefill_fn is None \
                     or self.decode_fn is None:
                 return rep
-            # degradation ladder: batched -> per-slot.  An engine built
-            # with BOTH the adapter and the closures degrades here instead
-            # of making the caller rebuild it (launch/serve.py still
-            # handles the adapter-only {"ok": False} by rebuilding).
+            # degradation ladder: batched -> per-slot, for an engine built
+            # with BOTH the adapter and the closures
             self.degraded = ("per-slot", rep.get("reason", ""))
             self.batched = None
         toks = np.zeros((1, prompt_len), np.int32)
@@ -343,8 +341,8 @@ class ServingEngine:
         if key in self._exe:
             return self._exe[key], "pinned"
         sds = jax.ShapeDtypeStruct
-        args = (sds((bk, L), jnp.int32), sds((bk,), jnp.int32),
-                sds((), jnp.int32))
+        args = (self.batched.params, sds((bk, L), jnp.int32),
+                sds((bk,), jnp.int32), sds((), jnp.int32))
         exe, src = self._cache().compile_cached(self.batched.prefill_fn,
                                                 args,
                                                 extra=self._key_salt())
@@ -359,10 +357,11 @@ class ServingEngine:
         sds = jax.ShapeDtypeStruct
         slots = self.scfg.batch_slots
         packed = self.batched.init_slots(slots, abstract=True)
-        args = (sds((slots,), jnp.int32), packed, sds((), jnp.int32))
+        args = (self.batched.params, sds((slots,), jnp.int32), packed,
+                sds((), jnp.int32))
         exe, src = self._cache().compile_cached(
             self.batched.step_fn, args, extra=self._key_salt(),
-            jit_kwargs={"donate_argnums": (1,)})
+            jit_kwargs={"donate_argnums": (2,)})
         self._exe[key] = exe
         self.compile_log.append(("decode_step", (slots,), src))
         return exe, src
@@ -375,8 +374,10 @@ class ServingEngine:
         slots = self.scfg.batch_slots
         packed = self.batched.init_slots(slots, abstract=True)
         cache = jax.eval_shape(
-            lambda t, n: self.batched.prefill_fn(t, n, jnp.int32(0))[1],
-            sds((bk, self.scfg.max_seq), jnp.int32), sds((bk,), jnp.int32))
+            lambda p, t, n: self.batched.prefill_fn(p, t, n,
+                                                    jnp.int32(0))[1],
+            self.batched.params, sds((bk, self.scfg.max_seq), jnp.int32),
+            sds((bk,), jnp.int32))
         args = (packed, cache, sds((), jnp.int32), sds((), jnp.int32))
         exe, src = self._cache().compile_cached(
             self.batched.write_slot_fn, args,
@@ -1012,7 +1013,8 @@ class ServingEngine:
                 if (self.admission is not None and not coop) else None
             try:
                 nxt, packed = self._call_step("decode", rids, step_exe,
-                                              toks, packed, np.int32(step_i),
+                                              self.batched.params, toks,
+                                              packed, np.int32(step_i),
                                               slots=slots)
             except PoisonError as e:
                 # raised before the step executed, so the donated packed
@@ -1101,6 +1103,7 @@ class ServingEngine:
                 rids = [s["rid"] for s in grp]
                 try:
                     first, cache = self._call_step("prefill", rids, exe,
+                                                   self.batched.params,
                                                    toks, lens,
                                                    np.int32(step_i),
                                                    slots=grp)

@@ -187,6 +187,61 @@ def test_legacy_key_warns():
 # memo store (file I/O only — tier-1)
 # ---------------------------------------------------------------------------
 
+def test_cache_root_follows_jax_compilation_cache_dir(monkeypatch,
+                                                     tmp_path):
+    """One variable places both caches: JAX's at its root, this repo's
+    executable store under ``repro/``; unset, both sit at a fixed path
+    in the checkout (never a temp name)."""
+    from repro.core import compile_cache as ccm
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert ccm.cache_root() == tmp_path
+    assert CompileCache().root == tmp_path / "repro"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = Path(__file__).resolve().parent.parent
+    assert ccm.cache_root() == checkout / ".cache" / "jax"
+    assert CompileCache().root == checkout / ".cache" / "jax" / "repro"
+
+
+def test_instance_key_folds_device_kind(monkeypatch):
+    """An executable built for one TPU generation is a miss on another."""
+    from repro.core import compile_cache as ccm
+
+    def f(x):
+        return x + 1
+
+    arg = (np.zeros((4,), np.float32),)
+    keys = set()
+    for kind in ("tpu/TPU v5 lite", "tpu/TPU v6 lite"):
+        monkeypatch.setattr(ccm, "toolchain_tag", lambda k=kind: k)
+        keys.add(instance_key(f, arg))
+    assert len(keys) == 2
+
+
+def test_disk_hit_keeps_the_executables_devices(tmp_path):
+    """A one-device executable loaded from disk on a host with several
+    devices runs on its own device, not spread over all of them."""
+    body = textwrap.dedent(f"""
+        import jax, jax.numpy as jnp
+        from repro.core.compile_cache import CompileCache
+        assert len(jax.devices()) == 4
+        x = jnp.arange(8.0)
+        cc = CompileCache(root={str(tmp_path)!r})
+        cc.compile_cached(lambda v: v * 2, (x,), key="k")
+        cc.clear_memory()
+        exe, src = cc.get_with_source("k")
+        assert src == "disk", src
+        print("OUT", exe(x).tolist())
+    """)
+    r = subprocess.run(
+        [sys.executable, "-c", body], capture_output=True, text=True,
+        timeout=300,
+        env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path),
+             "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OUT [0.0, 2.0, 4.0" in r.stdout
+
+
 def test_memo_roundtrip_and_corrupt_recovery(tmp_path):
     cc = CompileCache(root=tmp_path)
     key = "ab" + "0" * 62
@@ -322,7 +377,7 @@ def test_cross_process_reuse_and_gaussian_zero_compiles(tmp_path):
             [sys.executable, "-c", body], capture_output=True, text=True,
             timeout=600,
             env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                 "REPRO_COMPILE_CACHE": str(tmp_path),
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
                  "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path)})
         assert r.returncode == 0, f"stderr:\n{r.stderr[-3000:]}"
         line = [l for l in r.stdout.splitlines() if l.startswith("REPORT")]

@@ -25,6 +25,7 @@ def run_sub(body: str, n_devices: int = 4) -> str:
             "--xla_force_host_platform_device_count={n_devices}"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
+        AUTO = jax.sharding.AxisType.Auto
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("SUBPROCESS_OK")
     """)
@@ -100,7 +101,7 @@ def test_pipeline_spmd_equivalence():
         from repro.distributed.pipeline import (pipeline_apply,
                                                 pipeline_loss_fn,
                                                 stack_stage_params)
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",), axis_types=(AUTO,))
         S, M, mb, d = 4, 8, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), S)
         per_stage = [{"w": jax.random.normal(k, (d, d)) * 0.3} for k in ks]
@@ -151,7 +152,7 @@ def test_sharded_train_step_matches_single_device():
         # single-device reference
         p1, s1, m1 = jax.jit(step)(params, state, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AUTO,) * 2)
         pol = shd.for_mesh(mesh)
         pshard = jax.tree.map(lambda s: NamedSharding(mesh, s),
                               shd.param_specs(cfg, mesh, pol))
@@ -207,7 +208,7 @@ def test_error_feedback_reduces_bias():
 def test_compressed_psum_shard_map():
     run_sub("""
         from repro.distributed import compress as C
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AUTO,))
         gs = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 8))
 
         def body(g):

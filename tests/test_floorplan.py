@@ -163,6 +163,17 @@ def test_channel_traffic_counts_full_run_bytes():
 # content addressing + memoization
 # ---------------------------------------------------------------------------
 
+def test_hw_peaks_keyed_by_device_kind():
+    """Peaks come from the device's own row; the CPU row is nominal and
+    an unknown kind is an error, never another chip's numbers."""
+    from repro.core.cost import HW, V5E, hw_peaks
+    assert hw_peaks(V5E)["peak_flops"] == 197e12
+    assert hw_peaks(V5E)["hbm_bw"] == 819e9
+    assert hw_peaks() is HW[jax.devices()[0].device_kind]
+    with pytest.raises(KeyError, match="TPU v9"):
+        hw_peaks("TPU v9")
+
+
 def test_placement_key_sensitivity():
     plan, graph = _plan(stages=2)
     h = graph.structural_hash()
@@ -173,6 +184,8 @@ def test_placement_key_sensitivity():
     assert placement_key(h, 2, {"Source": 1}) \
         != placement_key(h, 2, {"Source": 0})
     assert base != placement_key(h + "x", 2)
+    from repro.core.cost import HW, V5E
+    assert base != placement_key(h, 2, hw=HW[V5E])
     assert base.startswith("place_")
 
 

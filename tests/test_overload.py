@@ -701,6 +701,7 @@ def test_overload_journal_is_byte_identical_across_processes(tmp_path):
             [sys.executable, "-c", _REPLAY_PROC, str(SEED), str(jp)],
             capture_output=True, text=True, timeout=300,
             env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
                  "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path)})
         assert r.returncode == 0, r.stderr[-3000:]
         blob = jp.read_bytes()

@@ -628,6 +628,7 @@ def test_sigkill_mid_stream_exactly_once(tmp_path):
     the journal and delivers every result exactly once, matching the
     fault-free oracle."""
     env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path)}
     jp = tmp_path / "j.jsonl"
 
@@ -681,6 +682,7 @@ def test_train_kill_and_resume_falls_past_corrupt_step(tmp_path):
     """SIGKILL a training run mid-flight, corrupt its newest checkpoint,
     and assert the rerun resumes from the previous verified step."""
     env = {"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path)}
     ckpt = tmp_path / "ckpt"
     cmd = [sys.executable, "-m", "repro.launch.train", "--arch",

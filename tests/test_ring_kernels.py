@@ -143,6 +143,26 @@ def test_ring_ops_trace_under_jit(impl):
     assert int(size) == 0
 
 
+@pytest.mark.parametrize("shape,dtype,tiled", [
+    ((1024, 128), jnp.bfloat16,
+     (1024, 16, 128)),                      # 16-bit rows: 16 sublanes
+    ((2, 256, 256), jnp.float32, (2, 256, 256)),   # aligned: kept as is
+    ((64, 16384), jnp.float32, (64, 128, 128)),
+    ((8,), jnp.int32, (8, 8, 128)),         # scalars pad to one tile
+    ((5, 3), jnp.bool_, (5, 8, 128)),       # bools ride as int32
+])
+def test_ring_rows_tile_aligned(shape, dtype, tiled):
+    """The DMA kernels move whole HBM tiles: every ring is viewed as
+    [rows, R, L] with (R, L) a multiple of the dtype's tile, and the
+    view round-trips exactly."""
+    x = jnp.asarray(np.arange(int(np.prod(shape))).reshape(shape) % 7,
+                    dtype)
+    t = ring._tiled(x)
+    assert t.shape == tiled
+    assert np.array_equal(np.asarray(ring._untiled(t, x.shape, x.dtype)),
+                          np.asarray(x))
+
+
 def test_dispatch_precedence(monkeypatch):
     # explicit arg > environment > backend fallback
     monkeypatch.setenv(ring.RING_ENV, "interpret")

@@ -45,7 +45,7 @@ def toy_batched_adapter(max_seq: int) -> ServingAdapter:
     cache is {"len": [slots], "last": [1, slots]} — every non-"len" leaf
     carries its batch on axis 1, exactly like the real KV pytree."""
 
-    def prefill_fn(tokens, true_len, step):
+    def prefill_fn(params, tokens, true_len, step):
         idx = jnp.clip(true_len - 1, 0, tokens.shape[1] - 1)
         last = jnp.take_along_axis(tokens, idx[:, None], axis=1)[:, 0]
         first = (last + 1) % V
@@ -53,7 +53,7 @@ def toy_batched_adapter(max_seq: int) -> ServingAdapter:
                  "last": first[None].astype(jnp.int32)}
         return first.astype(jnp.int32), cache
 
-    def step_fn(tokens, packed, step):
+    def step_fn(params, tokens, packed, step):
         live = packed["len"] > 0
         nxt = jnp.where(live, (tokens + 1) % V, 0).astype(jnp.int32)
         return nxt, {"len": jnp.where(live, packed["len"] + 1, 0),
@@ -337,7 +337,7 @@ def test_second_serving_process_compiles_nothing(tmp_path):
             [sys.executable, "-c", _SERVE_PROC], capture_output=True,
             text=True, timeout=600,
             env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-                 "REPRO_COMPILE_CACHE": str(tmp_path),
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
                  "JAX_PLATFORMS": "cpu", "HOME": str(tmp_path)})
         assert r.returncode == 0, f"stderr:\n{r.stderr[-3000:]}"
         line = [ln for ln in r.stdout.splitlines()
@@ -353,3 +353,16 @@ def test_second_serving_process_compiles_nothing(tmp_path):
     assert warm["warmup"]["decode"] == "disk"
     lazy = [(k, tuple(s)) for k, s, src in warm["log"] if src == "compiled"]
     assert lazy == [], lazy
+
+
+def test_serve_launcher_batched_warmup_failure_is_an_error(monkeypatch):
+    """A batched warmup the device refuses stops the launcher with the
+    reason; it never reruns per-slot and exits 0."""
+    from repro.launch import serve as launcher
+
+    monkeypatch.setattr(ServingEngine, "warmup",
+                        lambda self, **kw: {"ok": False,
+                                            "reason": "kernel refused"})
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        launcher.serve(["--arch", "qwen3-0.6b", "--requests", "1",
+                        "--max-new", "1", "--max-seq", "16"])
