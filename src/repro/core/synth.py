@@ -78,6 +78,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from .channel import Channel, IStream, OStream
 from .compile_cache import (_stable_repr, aval_signature, default_cache,
@@ -94,7 +95,7 @@ from ..kernels.dispatch import resolve_impl
 from ..kernels.ring import (RING_CHOICES, RING_ENV, eval_guards, ring_pop,
                             ring_push)
 
-SYNTH_SCHEMA = "synth3"
+SYNTH_SCHEMA = "synth4"
 
 
 def _canon_dtype(dtype: Any) -> np.dtype:
@@ -660,6 +661,7 @@ class _Plan:
         self.port_dirs: list[set] = []              # pi -> {"read","write"}
         self.ring_impl: str = "xla"
         self.tasks: list[_TaskPlan] = []
+        self.n_phase_traces = 0     # jax.eval_shape calls of _count_phase
 
     def chan_index(self, c: Channel) -> int:
         i = self._chan_idx.get(id(c))
@@ -784,6 +786,7 @@ def _count_phase(plan: _Plan, tp: _TaskPlan, label: str, fn: Callable,
     rec = _Recorder(tp.inst)
     probe = _phase_probe(plan, tp, fn, rec)
     spec = _state_spec(tp.state0)
+    plan.n_phase_traces += 1
     try:
         out_state, _, _ = jax.eval_shape(
             probe, spec, _chan_specs(plan, tp), _mmap_specs(plan, tp))
@@ -1382,6 +1385,7 @@ class CompiledEngine(EngineBase):
         self.compile_s = 0.0            # executable resolve (compile/load)
         self.ring_impl_used: Optional[str] = None
         self.n_sweeps = 0
+        self.n_phase_traces = 0         # _lower's jax.eval_shape traces
         self.placement_used = None      # floorplan.Placement after a run
         self.partition_source = None    # "partitioned" | "memo" | None
 
@@ -1554,6 +1558,7 @@ class CompiledEngine(EngineBase):
             graph.validate()
         except GraphValidationError as e:
             raise SynthesisError(f"graph failed validation: {e}") from e
+        self.n_phase_traces = plan.n_phase_traces
         return plan, graph
 
     def _cache_key(self, graph, args: tuple, ring_impl: str = "xla",
@@ -1566,41 +1571,63 @@ class CompiledEngine(EngineBase):
         return h.hexdigest()
 
     # -- run -----------------------------------------------------------------
+    def _exec_root(self, top: Callable, args: tuple, kwargs: dict):
+        """Execute the wiring bodies under a fresh root instance; the
+        caller owns ``clear_context()``."""
+        root = TaskInstance(top, args, kwargs, detach=False, parent=None,
+                            name=getattr(top, "__name__", "top"))
+        set_context(self, None)
+        self._register(root)
+        return self._exec(root)
+
     def _elaborate(self, top: Callable, *args, **kwargs):
         """Execute the wiring bodies and lower to a plan, without running
         the compiled program.  Returns ``(plan, graph, result)`` — the
         shared front half of :meth:`run`, also used by the recovery
         subsystem to build its chunk schedule.  The caller owns
         ``clear_context()``."""
-        root = TaskInstance(top, args, kwargs, detach=False, parent=None,
-                            name=getattr(top, "__name__", "top"))
-        set_context(self, None)
-        self._register(root)
-        result = self._exec(root)
+        result = self._exec_root(top, args, kwargs)
         plan, graph = self._lower()
         return plan, graph, result
 
     def run(self, top: Callable, *args, **kwargs) -> SimReport:
+        """Elaborate, lower, key, resolve, copy in, execute, write back.
+
+        Each stage runs under a ``jax.profiler.TraceAnnotation`` named
+        ``compiled.<stage>``, nested in one ``compiled.run``, so that a
+        profiler trace puts the device's idle time down to a stage; the
+        annotations record nothing unless a trace is active."""
         t0 = time.perf_counter()
-        try:
-            plan, graph, result = self._elaborate(top, *args, **kwargs)
-            if self.mesh is not None:
-                return self._run_partitioned(plan, graph, result, t0)
-            states0 = tuple(tp.state0 for tp in plan.tasks)
-            mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
-            ports0 = tuple(_port_carry0(p) for p in plan.ports)
-            program = _build_program(plan)
-            key = self._cache_key(graph, (states0, mmaps0, ports0),
-                                  plan.ring_impl)
-            exe = self._resolve(program, (states0, mmaps0, ports0), key)
-            mm_final, ports_final, fires, sweeps, maxocc, sizes = exe(
-                states0, mmaps0, ports0)
-            self._writeback_ports(plan, ports_final)
-            self._fill_port_stats(plan, ports_final)
-            return self._finish(plan, mm_final, fires, sweeps, maxocc,
-                                sizes, result, t0)
-        finally:
-            clear_context()
+        with _span("compiled.run"):
+            try:
+                with _span("compiled.elaborate"):
+                    result = self._exec_root(top, args, kwargs)
+                with _span("compiled.lower"):
+                    plan, graph = self._lower()
+                    if self.mesh is None:
+                        program = _build_program(plan)
+                if self.mesh is not None:
+                    return self._run_partitioned(plan, graph, result, t0)
+                with _span("compiled.copy_in"):
+                    states0 = tuple(tp.state0 for tp in plan.tasks)
+                    mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
+                    ports0 = tuple(_port_carry0(p) for p in plan.ports)
+                with _span("compiled.key"):
+                    key = self._cache_key(graph, (states0, mmaps0, ports0),
+                                          plan.ring_impl)
+                with _span("compiled.resolve"):
+                    exe = self._resolve(program, (states0, mmaps0, ports0),
+                                        key)
+                with _span("compiled.execute"):
+                    out = jax.block_until_ready(exe(states0, mmaps0, ports0))
+                mm_final, ports_final, fires, sweeps, maxocc, sizes = out
+                with _span("compiled.writeback"):
+                    self._writeback_ports(plan, ports_final)
+                    self._fill_port_stats(plan, ports_final)
+                    return self._finish(plan, mm_final, fires, sweeps,
+                                        maxocc, sizes, result, t0)
+            finally:
+                clear_context()
 
     def _resolve(self, program: Callable, args: tuple, key: str):
         """The executable for ``program`` through the compile cache (or a
@@ -1653,47 +1680,54 @@ class CompiledEngine(EngineBase):
                 f"device's sweep and has no cut protocol; run the graph "
                 f"single-device (mesh=None) or route the memory traffic "
                 f"through channels")
-        if isinstance(self.placement, Placement):
-            placement = self.placement
-            if placement.n_devices != n_dev or \
-                    len(placement.owners) != len(plan.tasks):
-                raise SynthesisError(
-                    f"placement reuse mismatch: placement is for "
-                    f"{placement.n_devices} devices / "
-                    f"{len(placement.owners)} tasks, graph has "
-                    f"{len(plan.tasks)} tasks on a {n_dev}-device mesh")
-        else:
-            placement = plan_placement(
-                plan, graph, n_dev, overrides=self.placement,
-                cache=self.cache)
+        with _span("compiled.place"):
+            if isinstance(self.placement, Placement):
+                placement = self.placement
+                if placement.n_devices != n_dev or \
+                        len(placement.owners) != len(plan.tasks):
+                    raise SynthesisError(
+                        f"placement reuse mismatch: placement is for "
+                        f"{placement.n_devices} devices / "
+                        f"{len(placement.owners)} tasks, graph has "
+                        f"{len(plan.tasks)} tasks on a {n_dev}-device mesh")
+            else:
+                placement = plan_placement(
+                    plan, graph, n_dev, overrides=self.placement,
+                    cache=self.cache)
         self.placement_used = placement
         self.partition_source = placement.source
         owners = np.asarray(placement.owners, np.int32)
 
-        states0 = tuple(tp.state0 for tp in plan.tasks)
-        mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
-        program = _build_partitioned_program(plan, owners, mesh, axis)
-        key = self._cache_key(
-            graph, (states0, mmaps0), plan.ring_impl,
-            extra=f"mesh={axis}:{n_dev}:owners={owners.tolist()}")
-        exe = self._resolve(program, (states0, mmaps0), key)
-        mm_st, fires_st, sweeps_st, maxocc_st, sizes_st = exe(
-            states0, mmaps0)
-        # authoritative rows: the writer's owner per written mmap (the
-        # one-writer rule makes it unique); anything replicated -> row 0
-        writer_of = {}
-        for ti, tp in enumerate(plan.tasks):
-            for ph in tp.phases:
-                for mi in ph.mmap_stores:
-                    writer_of[mi] = int(owners[ti])
-        mm_final = tuple(np.asarray(m)[writer_of.get(mi, 0)]
-                         for mi, m in enumerate(mm_st))
-        fires = np.asarray(fires_st)[0]
-        sweeps = np.asarray(sweeps_st)[0]
-        maxocc = np.asarray(maxocc_st)[0]
-        sizes = np.asarray(sizes_st)[0]
-        return self._finish(plan, mm_final, fires, sweeps, maxocc, sizes,
-                            result, t0)
+        with _span("compiled.lower"):
+            program = _build_partitioned_program(plan, owners, mesh, axis)
+        with _span("compiled.copy_in"):
+            states0 = tuple(tp.state0 for tp in plan.tasks)
+            mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
+        with _span("compiled.key"):
+            key = self._cache_key(
+                graph, (states0, mmaps0), plan.ring_impl,
+                extra=f"mesh={axis}:{n_dev}:owners={owners.tolist()}")
+        with _span("compiled.resolve"):
+            exe = self._resolve(program, (states0, mmaps0), key)
+        with _span("compiled.execute"):
+            mm_st, fires_st, sweeps_st, maxocc_st, sizes_st = \
+                jax.block_until_ready(exe(states0, mmaps0))
+        with _span("compiled.writeback"):
+            # authoritative rows: the writer's owner per written mmap (the
+            # one-writer rule makes it unique); anything replicated -> row 0
+            writer_of = {}
+            for ti, tp in enumerate(plan.tasks):
+                for ph in tp.phases:
+                    for mi in ph.mmap_stores:
+                        writer_of[mi] = int(owners[ti])
+            mm_final = tuple(np.asarray(m)[writer_of.get(mi, 0)]
+                             for mi, m in enumerate(mm_st))
+            fires = np.asarray(fires_st)[0]
+            sweeps = np.asarray(sweeps_st)[0]
+            maxocc = np.asarray(maxocc_st)[0]
+            sizes = np.asarray(sizes_st)[0]
+            return self._finish(plan, mm_final, fires, sweeps, maxocc,
+                                sizes, result, t0)
 
     def _finish(self, plan: _Plan, mm_final, fires, sweeps, maxocc,
                 sizes, result, t0: float) -> SimReport:

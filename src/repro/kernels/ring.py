@@ -28,6 +28,9 @@ via :mod:`repro.kernels.dispatch`:
   default; identical integer index math to the kernels, so every
   graph keeps a bit-exact reference lowering).
 
+The ring kernels are named ``ring_pop`` and ``ring_push``: the HLO
+instruction name that a profile shows for each call.
+
 Select with ``impl=`` or ``$REPRO_RING_IMPL``.  All three impls are
 exact integer/copy ops — no arithmetic reassociation — so parity is
 bitwise, not approximate.
@@ -94,9 +97,10 @@ def _untiled(t: jax.Array, like_shape: tuple, dtype) -> jax.Array:
     return flat.reshape(like_shape).astype(dtype)
 
 
-def _ring_call(kernel, out_shape, scalar, *arrays, aliases=None,
+def _ring_call(name: str, kernel, out_shape, scalar, *arrays, aliases=None,
                interpret: bool):
-    """One-step ``pallas_call`` with every array left in HBM
+    """One-step ``pallas_call`` named ``name`` (the name profiles and
+    HLO dumps give the kernel) with every array left in HBM
     (``pl.ANY``): the kernel moves rows by DMA at the dynamic offset in
     ``scalar`` (scalar-prefetched), so no VMEM block ever holds the ring."""
     return pl.pallas_call(
@@ -110,6 +114,7 @@ def _ring_call(kernel, out_shape, scalar, *arrays, aliases=None,
         out_shape=out_shape,
         input_output_aliases=aliases or {},
         interpret=interpret,
+        name=name,
     )(jnp.asarray(scalar, jnp.int32).reshape(1), *arrays)
 
 
@@ -168,7 +173,7 @@ def ring_pop(buf: jax.Array, head: jax.Array, size: jax.Array, n: int, *,
         return buf[idx], new_head, new_size
     tbuf = _tiled(buf)
     out = _ring_call(
-        partial(_pop_kernel, n, cap),
+        "ring_pop", partial(_pop_kernel, n, cap),
         jax.ShapeDtypeStruct((n,) + tbuf.shape[1:], tbuf.dtype),
         head, tbuf, interpret=impl == "interpret")
     toks = _untiled(out, (n,) + buf.shape[1:], buf.dtype)
@@ -202,7 +207,7 @@ def ring_push(buf: jax.Array, head: jax.Array, size: jax.Array,
         return buf.at[idx].set(arr), head, new_size
     tbuf = _tiled(buf)
     out = _ring_call(
-        partial(_push_kernel, n, cap),
+        "ring_push", partial(_push_kernel, n, cap),
         jax.ShapeDtypeStruct(tbuf.shape, tbuf.dtype),
         (head + size) % cap, tbuf, _tiled(arr.astype(buf.dtype)),
         aliases={1: 0}, interpret=impl == "interpret")
