@@ -23,6 +23,11 @@ the world.  This module supplies the missing halves:
     (``<root>/v1/memo/<hh>/<digest>.json``), used by the QoR-tuning loop in
     ``benchmarks/perf_iter.py`` to skip re-measuring unchanged variants.
 
+4.  **Lowering memo** — in memory only: the per-phase I/O counts that
+    ``CompiledEngine`` traces a task graph for, keyed by the graph's
+    structural hash, so re-invoking an unchanged graph traces nothing
+    (``docs/synthesis.md``).
+
 The cache is what makes the paper's edit-compile-measure cycle fast across
 *runs*: edit one of gaussian's definitions and only that definition pays an
 XLA compile — everything else is a digest lookup.  See ``docs/codegen.md``.
@@ -450,6 +455,9 @@ class CompileCache:
         self.faults = faults
         self.stats = CacheStats()
         self._mem: dict[str, Any] = {}
+        # counted phase plans of lowered task graphs (``synth._lower``),
+        # keyed by structure; memory only, like a process's traces
+        self._lowered: dict[str, Any] = {}
         self._lock = threading.RLock()
         # running estimate of on-disk bytes; None until the first full
         # walk.  Keeps the per-put cost O(1): the tree is only re-walked
@@ -583,6 +591,16 @@ class CompileCache:
             source = "compiled"
         return exe, source
 
+    # -- counted phase plans (CompiledEngine lowering) ------------------------
+
+    def lowering_get(self, key: str) -> Optional[Any]:
+        with self._lock:
+            return self._lowered.get(key)
+
+    def lowering_put(self, key: str, counts: Any) -> None:
+        with self._lock:
+            self._lowered[key] = counts
+
     # -- memoized JSON results (QoR-tuning measurements) ---------------------
 
     def memo_get(self, key: str) -> Optional[Any]:
@@ -684,9 +702,11 @@ class CompileCache:
         return dropped
 
     def clear_memory(self) -> None:
-        """Drop the first level (what a process restart does for free)."""
+        """Drop the first level and the lowering memo (what a process
+        restart does for free)."""
         with self._lock:
             self._mem.clear()
+            self._lowered.clear()
 
     def clear(self) -> None:
         self.clear_memory()

@@ -662,6 +662,7 @@ class _Plan:
         self.ring_impl: str = "xla"
         self.tasks: list[_TaskPlan] = []
         self.n_phase_traces = 0     # jax.eval_shape calls of _count_phase
+        self.structural_hash = ""   # the graph's, keying memo and executable
 
     def chan_index(self, c: Channel) -> int:
         i = self._chan_idx.get(id(c))
@@ -824,6 +825,54 @@ def _count_phase(plan: _Plan, tp: _TaskPlan, label: str, fn: Callable,
                       mmap_stores=rec.mmap_stores,
                       mmap_load_ops=rec.mmap_load_ops,
                       mmap_store_ops=rec.mmap_store_ops)
+
+
+# A phase's per-firing counts, each keyed by an index into the task's own
+# channel or mmap list.  The lowering memo stores them by position in that
+# list, so they apply to any invocation of the same structure whatever
+# global indices it assigns.
+_COUNTS = (("reads", "chan_ids"), ("writes", "chan_ids"),
+           ("mmap_loads", "mmap_ids"), ("mmap_stores", "mmap_ids"),
+           ("mmap_load_ops", "mmap_ids"), ("mmap_store_ops", "mmap_ids"))
+
+
+def _lowering_key(structural_hash: str, ring_impl: str) -> str:
+    """Key of a graph's counted phase plans: its structure covers every
+    input of a phase trace (step bodies and ``init`` by content, channel
+    specs, mmap avals, scalars); the rest is what shapes the traced avals
+    (``_mmap_specs`` canonicalises dtypes under the x64 flag)."""
+    h = hashlib.sha256()
+    h.update(f"lower:{structural_hash}:ring={ring_impl}:{SYNTH_SCHEMA}:"
+             f"jax:{jax.__version__}:{toolchain_tag()}:"
+             f"x64={jax.config.jax_enable_x64}".encode())
+    return h.hexdigest()
+
+
+def _positional_counts(tp: _TaskPlan, ph: _PhasePlan) -> tuple:
+    return tuple(
+        tuple((getattr(tp, ids).index(i), n)
+              for i, n in getattr(ph, name).items())
+        for name, ids in _COUNTS)
+
+
+def _remembered_phase(plan: _Plan, tp: _TaskPlan, label: str, fn: Callable,
+                      count: int, counts: tuple) -> _PhasePlan:
+    """A phase plan from the memo's counts, with the endpoint and
+    direction registrations that counting would have made (``_account``):
+    ``graph.validate()`` and the one-writer check read them."""
+    ph = _PhasePlan(label=label, fn=fn, count=count, **{
+        name: {getattr(tp, ids)[k]: n for k, n in c}
+        for (name, ids), c in zip(_COUNTS, counts, strict=True)})
+    for ci in ph.reads:
+        plan.channels[ci]._bind("consumer", tp.inst)
+    for ci in ph.writes:
+        plan.channels[ci]._bind("producer", tp.inst)
+    for op, mis in (("read", ph.mmap_loads), ("write", ph.mmap_stores)):
+        for mi in mis:
+            b = plan.mmaps[mi]._by_inst.get(tp.inst.uid)
+            if b is not None:
+                b.direction.add(op)
+    return ph
 
 
 # ---------------------------------------------------------------------------
@@ -1386,6 +1435,7 @@ class CompiledEngine(EngineBase):
         self.ring_impl_used: Optional[str] = None
         self.n_sweeps = 0
         self.n_phase_traces = 0         # _lower's jax.eval_shape traces
+        self.lower_source = None        # "traced" | "memory" | None
         self.placement_used = None      # floorplan.Placement after a run
         self.partition_source = None    # "partitioned" | "memo" | None
 
@@ -1464,6 +1514,9 @@ class CompiledEngine(EngineBase):
 
     # -- lowering ------------------------------------------------------------
     def _lower(self) -> tuple[_Plan, Any]:
+        """Plan the graph: bind each StepTask's ports, count each phase's
+        I/O rates (a ``jax.eval_shape`` per phase, or the compile cache's
+        memo of a structure lowered before), then check the whole."""
         step_insts = [i for i in self.instances
                       if getattr(i.fn, "is_step_task", False)]
         if not step_insts:
@@ -1498,10 +1551,18 @@ class CompiledEngine(EngineBase):
                     f"channel {c.name!r} has no declared element spec; "
                     f"synthesis sizes its ring buffer from "
                     f"Channel(dtype=..., shape=...)")
-        for tp in plan.tasks:
-            for label, fn, count in tp.task.phases():
+        graph = extract_graph(self)
+        plan.structural_hash = graph.structural_hash()
+        cc = self._store()
+        lkey = _lowering_key(plan.structural_hash, plan.ring_impl)
+        memo = cc.lowering_get(lkey) if cc is not None else None
+        self.lower_source = "traced" if memo is None else "memory"
+        for ti, tp in enumerate(plan.tasks):
+            for pi, (label, fn, count) in enumerate(tp.task.phases()):
                 tp.phases.append(
-                    _count_phase(plan, tp, label, fn, count))
+                    _count_phase(plan, tp, label, fn, count) if memo is None
+                    else _remembered_phase(plan, tp, label, fn, count,
+                                           memo[ti][pi]))
             if not tp.phases:
                 raise SynthesisError(
                     f"task {tp.inst.name!r} has zero total firings")
@@ -1553,18 +1614,28 @@ class CompiledEngine(EngineBase):
                     f"by {sorted(others)}: cross-task read-after-write "
                     f"through memory is schedule-dependent; route the "
                     f"value through a channel instead")
-        graph = extract_graph(self)
         try:
             graph.validate()
         except GraphValidationError as e:
             raise SynthesisError(f"graph failed validation: {e}") from e
         self.n_phase_traces = plan.n_phase_traces
+        if cc is not None and memo is None:
+            cc.lowering_put(lkey, tuple(
+                tuple(_positional_counts(tp, ph) for ph in tp.phases)
+                for tp in plan.tasks))
         return plan, graph
 
-    def _cache_key(self, graph, args: tuple, ring_impl: str = "xla",
-                   extra: str = "") -> str:
+    def _store(self):
+        """The compile cache this engine uses, or None with
+        ``cache=False``."""
+        if self.cache is False:
+            return None
+        return self.cache if self.cache is not None else default_cache()
+
+    def _cache_key(self, structural_hash: str, args: tuple,
+                   ring_impl: str = "xla", extra: str = "") -> str:
         h = hashlib.sha256()
-        h.update(graph.structural_hash().encode())
+        h.update(structural_hash.encode())
         h.update(_stable_repr(aval_signature(args, {})).encode())
         h.update(f"jax:{jax.__version__}:{toolchain_tag()}:"
                  f"{SYNTH_SCHEMA}:ring={ring_impl}:{extra}".encode())
@@ -1613,7 +1684,8 @@ class CompiledEngine(EngineBase):
                     mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
                     ports0 = tuple(_port_carry0(p) for p in plan.ports)
                 with _span("compiled.key"):
-                    key = self._cache_key(graph, (states0, mmaps0, ports0),
+                    key = self._cache_key(plan.structural_hash,
+                                          (states0, mmaps0, ports0),
                                           plan.ring_impl)
                 with _span("compiled.resolve"):
                     exe = self._resolve(program, (states0, mmaps0, ports0),
@@ -1634,11 +1706,11 @@ class CompiledEngine(EngineBase):
         plain compile with ``cache=False``); records its source, key and
         the seconds it took."""
         t0 = time.perf_counter()
-        if self.cache is False:
+        cc = self._store()
+        if cc is None:
             exe = jax.jit(program).lower(*args).compile()
             source = "compiled"
         else:
-            cc = self.cache if self.cache is not None else default_cache()
             exe, source = cc.compile_cached(program, args, key=key)
         self.compile_source = source
         self.compile_key = key
@@ -1705,7 +1777,7 @@ class CompiledEngine(EngineBase):
             mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
         with _span("compiled.key"):
             key = self._cache_key(
-                graph, (states0, mmaps0), plan.ring_impl,
+                plan.structural_hash, (states0, mmaps0), plan.ring_impl,
                 extra=f"mesh={axis}:{n_dev}:owners={owners.tolist()}")
         with _span("compiled.resolve"):
             exe = self._resolve(program, (states0, mmaps0), key)

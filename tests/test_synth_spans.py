@@ -98,12 +98,12 @@ def test_phase_traces_count_the_plans_phases(tmp_path):
     phases = sum(len(tp.phases) for tp in plan.tasks)
     assert phases > len(plan.tasks)         # PEs have a flush phase
     cc = CompileCache(root=tmp_path / "cc")
-    for _ in range(2):
+    for want in (phases, 0):        # traced, then from the lowering memo
         top, args, _ = gemm.build_step(P=2, n=4, K=2)
         eng = repro.ENGINES["compiled"](cache=cc)
         assert eng.n_phase_traces == 0
         eng.run(top, *args)
-        assert eng.n_phase_traces == phases
+        assert eng.n_phase_traces == want
 
 
 # The partitioned path needs four devices, which a CPU backend gives only
@@ -125,10 +125,20 @@ _CHILD = textwrap.dedent("""
     plan, graph, _ = synth.elaborate_step_graph(top, *args)
     placement = plan_placement(plan, graph, 4, cache=False,
                                cost_fn=lambda plan, tp: 1.0)
-    eng = repro.ENGINES["compiled"](mesh=4, placement=placement,
-                                    cache=CompileCache(root={cc!r}))
+    cc = CompileCache(root={cc!r})
+    eng = repro.ENGINES["compiled"](mesh=4, placement=placement, cache=cc)
     top, args, check = gemm.build_step(P=2, n=4, K=2)
     spans = _traced(lambda: eng.run(top, *args), {trace!r})
+    # a second partitioned run of the same structure lowers from the memo
+    again = repro.ENGINES["compiled"](mesh=4, placement=placement, cache=cc)
+    top2, args2, check2 = gemm.build_step(P=2, n=4, K=2)
+    again.run(top2, *args2)
+    memo = {{
+        "sources": [eng.lower_source, again.lower_source],
+        "traces": [eng.n_phase_traces, again.n_phase_traces],
+        "ok": bool(check2()[0]),
+        "same": all(np.array_equal(a.data, b.data)
+                    for a, b in zip(args[2], args2[2]))}}
     # the ring kernels' names and the cut exchange's collective permute,
     # which the benchmark's readers match in a chip trace
     plan.ring_impl = "interpret"
@@ -140,7 +150,7 @@ _CHILD = textwrap.dedent("""
         tuple(np.asarray(m.data) for m in plan.mmaps)).as_text(
             debug_info=True)
     print(json.dumps({{
-        "ok": bool(check()[0]), "spans": spans,
+        "ok": bool(check()[0]), "spans": spans, "memo": memo,
         "named": [k for k in ("collective_permute", "ring_push", "ring_pop")
                   if k in text]}}))
 """)
@@ -157,6 +167,10 @@ def test_partitioned_run_spans_and_named_kernels(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     got = json.loads(r.stdout.strip().splitlines()[-1])
     assert got["ok"]
+    phases = got["memo"]["traces"][0]
+    assert got["memo"] == {"sources": ["traced", "memory"],
+                           "traces": [phases, 0], "ok": True, "same": True}
+    assert phases > 0
     assert got["named"] == ["collective_permute", "ring_push", "ring_pop"]
     spans = [tuple(s) for s in got["spans"]]
     (run, kids), = _children(spans).items()
