@@ -95,7 +95,7 @@ from ..kernels.dispatch import resolve_impl
 from ..kernels.ring import (RING_CHOICES, RING_ENV, eval_guards, ring_pop,
                             ring_push)
 
-SYNTH_SCHEMA = "synth4"
+SYNTH_SCHEMA = "synth5"
 
 
 def _canon_dtype(dtype: Any) -> np.dtype:

@@ -237,7 +237,8 @@ def eval_guards(sizes: jax.Array, caps, need_r: jax.Array,
         ``fire[t] = live[t] & all_c(need_r[t,c] <= sizes[c])
                             & all_c(need_w[t,c] <= caps[c] - sizes[c])``
 
-    Pure integer comparisons — bit-identical across all impls.
+    Pure integer comparisons — bit-identical across all impls.  The
+    Pallas call is named ``eval_guards``, the name a profile gives it.
     """
     impl = _resolve(impl)
     caps = jnp.asarray(caps, jnp.int32)
@@ -261,5 +262,6 @@ def eval_guards(sizes: jax.Array, caps, need_r: jax.Array,
         _guard_kernel,
         out_shape=jax.ShapeDtypeStruct((t_p, _LANE), jnp.int32),
         interpret=impl == "interpret",
+        name="eval_guards",
     )(pad2(need_r), pad2(need_w), row(sizes), row(caps - sizes), live_m)
     return out[:t, 0] > 0

@@ -14,12 +14,14 @@ import sys
 import textwrap
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
 import repro  # noqa: E402
 from repro.apps import gemm  # noqa: E402
+from repro.core import synth  # noqa: E402
 from repro.core.compile_cache import CompileCache  # noqa: E402
 from repro.core.synth import elaborate_step_graph  # noqa: E402
 
@@ -180,3 +182,20 @@ def test_partitioned_run_spans_and_named_kernels(tmp_path):
         "compiled.elaborate", "compiled.lower", "compiled.place",
         "compiled.lower", *STAGES[2:]]
     assert _covered(run, kids) >= 0.95
+
+
+def test_single_device_program_names_guard_kernel():
+    """The fused guard evaluation is a Pallas call named ``eval_guards``
+    (the benchmark's ``guard_kernel_ms`` matches that name in a chip
+    trace); the name opens a scope over the call in the lowered program,
+    as the ring kernels' names do."""
+    top, args, _ = gemm.build_step(P=2, n=4, K=2)
+    plan, _, _ = elaborate_step_graph(top, *args)
+    plan.ring_impl = "interpret"
+    text = jax.jit(synth._build_program(plan)).lower(
+        tuple(tp.state0 for tp in plan.tasks),
+        tuple(np.asarray(m.data) for m in plan.mmaps),
+        tuple(synth._port_carry0(p) for p in plan.ports)).as_text(
+            debug_info=True)
+    for kernel in ("eval_guards", "ring_push", "ring_pop"):
+        assert f"/{kernel}/pallas_call" in text, kernel

@@ -14,6 +14,7 @@ where no v5e topology can be described.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -98,6 +99,23 @@ def test_eval_guards_compiles(spec):
 
     _assert_mosaic(guards, spec((c,), jnp.int32), spec((t, c), jnp.int32),
                    spec((t, c), jnp.int32), spec((t,), jnp.bool_))
+
+
+def test_eval_guards_named_at_paper_array(spec):
+    """The guard kernel of the paper's 13x13 systolic GEMM (208 tasks,
+    507 channels) is an instruction named ``eval_guards``, the name a
+    chip trace gives its device time."""
+    t, c = 208, 507
+
+    def guards(sizes, nr, nw, live):
+        return ring.eval_guards(sizes, np.full((c,), 2, np.int32), nr, nw,
+                                live, impl="pallas")
+
+    text = jax.jit(guards).lower(
+        spec((c,), jnp.int32), spec((t, c), jnp.int32),
+        spec((t, c), jnp.int32), spec((t,), jnp.bool_)).compile().as_text()
+    assert re.search(r"^\s*%eval_guards(\.\d+)? = .*custom-call\(", text,
+                     re.M)
 
 
 def test_decode_attention_compiles(spec):
