@@ -1257,9 +1257,10 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
     sweep-end sizes rather than after every firing.)
 
     Outputs are stacked across the mesh axis (every leaf gains a
-    leading device dimension); the caller selects the authoritative row
-    — the writer task's owner for each written mmap, any row for the
-    replicated fires/sweeps/maxocc/sizes.
+    leading device dimension); the caller fetches only the authoritative
+    shard — the writer task's owner's for each written mmap, row 0's for
+    the replicated fires/sweeps/maxocc/sizes — and never an unwritten
+    mmap.
     """
     if plan.ports:
         raise SynthesisError(
@@ -1390,6 +1391,14 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
     return program
 
 
+def _row_shard(stacked: jax.Array, row: int) -> jax.Array:
+    """The device-resident shard of a mesh-stacked output that holds
+    ``row`` of its leading device axis (found by the shard's index, not
+    by device order), shaped ``(1, ...)``."""
+    return next(s.data for s in stacked.addressable_shards
+                if (s.index[0].start or 0) == row)
+
+
 # ---------------------------------------------------------------------------
 # the fourth engine
 # ---------------------------------------------------------------------------
@@ -1434,6 +1443,7 @@ class CompiledEngine(EngineBase):
         self.compile_s = 0.0            # executable resolve (compile/load)
         self.ring_impl_used: Optional[str] = None
         self.n_sweeps = 0
+        self.writeback_bytes = 0        # mmap/port results fetched to host
         self.n_phase_traces = 0         # _lower's jax.eval_shape traces
         self.lower_source = None        # "traced" | "memory" | None
         self.placement_used = None      # floorplan.Placement after a run
@@ -1669,6 +1679,7 @@ class CompiledEngine(EngineBase):
         profiler trace puts the device's idle time down to a stage; the
         annotations record nothing unless a trace is active."""
         t0 = time.perf_counter()
+        self.writeback_bytes = 0
         with _span("compiled.run"):
             try:
                 with _span("compiled.elaborate"):
@@ -1786,20 +1797,28 @@ class CompiledEngine(EngineBase):
                 jax.block_until_ready(exe(states0, mmaps0))
         with _span("compiled.writeback"):
             # authoritative rows: the writer's owner per written mmap (the
-            # one-writer rule makes it unique); anything replicated -> row 0
+            # one-writer rule makes it unique); anything replicated -> row
+            # 0.  Only those shards leave the device, in one device_get so
+            # the chips' transfers overlap; _writeback reads no unwritten
+            # mmap, so those stay on the device
             writer_of = {}
             for ti, tp in enumerate(plan.tasks):
                 for ph in tp.phases:
                     for mi in ph.mmap_stores:
                         writer_of[mi] = int(owners[ti])
-            mm_final = tuple(np.asarray(m)[writer_of.get(mi, 0)]
-                             for mi, m in enumerate(mm_st))
-            fires = np.asarray(fires_st)[0]
-            sweeps = np.asarray(sweeps_st)[0]
-            maxocc = np.asarray(maxocc_st)[0]
-            sizes = np.asarray(sizes_st)[0]
-            return self._finish(plan, mm_final, fires, sweeps, maxocc,
-                                sizes, result, t0)
+            written = sorted(writer_of)
+            rows = jax.device_get(
+                [_row_shard(mm_st[mi], writer_of[mi]) for mi in written]
+                + [_row_shard(x, 0)
+                   for x in (fires_st, sweeps_st, maxocc_st, sizes_st)])
+            rows = [r[0] for r in rows]
+            mm_final = [None] * len(mm_st)
+            for mi, row in zip(written, rows):
+                mm_final[mi] = row
+                self.writeback_bytes += row.nbytes
+            fires, sweeps, maxocc, sizes = rows[len(written):]
+            return self._finish(plan, tuple(mm_final), fires, sweeps,
+                                maxocc, sizes, result, t0)
 
     def _finish(self, plan: _Plan, mm_final, fires, sweeps, maxocc,
                 sizes, result, t0: float) -> SimReport:
@@ -1845,17 +1864,25 @@ class CompiledEngine(EngineBase):
                 written.update(ph.mmap_stores)
         for mi in sorted(written):
             m = plan.mmaps[mi]
-            out = np.asarray(mm_final[mi])
+            out = self._to_host(mm_final[mi])
             if isinstance(m.data, np.ndarray):
                 np.copyto(m.data, out)
             else:
                 m.data = out
 
+    def _to_host(self, x) -> np.ndarray:
+        """``x`` as a host array; a device array's bytes count in
+        ``writeback_bytes``."""
+        out = np.asarray(x)
+        if isinstance(x, jax.Array):
+            self.writeback_bytes += out.nbytes
+        return out
+
     def _writeback_ports(self, plan: _Plan, ports_final: tuple) -> None:
         for pi, (p, pc) in enumerate(zip(plan.ports, ports_final)):
             if "write" not in plan.port_dirs[pi]:
                 continue
-            out = np.asarray(pc[_P_DATA])
+            out = self._to_host(pc[_P_DATA])
             if isinstance(p.data, np.ndarray):
                 np.copyto(p.data, out)
             else:

@@ -1,0 +1,142 @@
+"""What a compiled run brings back from the device, and that it is enough.
+
+The partitioned program's outputs are stacked over the mesh, one row per
+device; ``CompiledEngine`` fetches only the writer's shard of each written
+mmap and one row of the replicated counters, and leaves read-only mmaps on
+the device.  ``writeback_bytes`` counts what came back.  These tests hold
+the partitioned runs byte-identical to the one-device program and the
+counter to the written mmaps' bytes, on either path.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+import repro  # noqa: E402
+from repro.apps import gemm  # noqa: E402
+from repro.core.compile_cache import CompileCache  # noqa: E402
+
+SRC = str(Path(__file__).resolve().parents[1] / "src")
+
+# The partitioned path needs up to four devices, which a CPU backend gives
+# only to a process that starts with the flag: every run happens in one
+# child, and each case below reads its share of the child's answer.
+_CHILD = textwrap.dedent("""
+    import json, sys
+    sys.path.insert(0, {src!r})
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+    import repro
+    from repro.apps import gemm, page_rank
+    from repro.core.compile_cache import CompileCache
+
+    cc = CompileCache(root={cc!r})
+
+    def build(app):
+        if app == "gemm":
+            top, args, check = gemm.build_step(P=2, n=4, K=2)
+            return top, args, check, list(args[2]), [args[0], args[1]]
+        top, args, check = page_rank.build_step(
+            n_vertices=16, n_edges=48, n_pe=2, n_iters=4)
+        r0, out, deg, edges, plans = args
+        return top, args, check, [out], [r0, deg, *edges, *plans]
+
+    def run(app, **kw):
+        top, args, check, written, read_only = build(app)
+        before = [np.array(m.data, copy=True) for m in read_only]
+        eng = repro.ENGINES["compiled"](cache=cc, **kw)
+        rep = eng.run(top, *args)
+        placed = {{}}
+        if eng.placement_used is not None:
+            placed = dict(zip(eng.placement_used.task_names,
+                              map(int, eng.placement_used.owners)))
+        return {{
+            "ok": bool(rep.ok and check()[0]),
+            "out": b"".join(np.asarray(m.data).tobytes()
+                            for m in written).hex(),
+            "read_only_same": all(
+                np.asarray(m.data).tobytes() == b.tobytes()
+                for m, b in zip(read_only, before)),
+            "written_nbytes": int(sum(np.asarray(m.data).nbytes
+                                      for m in written)),
+            "writeback_bytes": int(eng.writeback_bytes),
+            "sweeps": int(eng.n_sweeps),
+            "placed": placed}}
+
+    devs = jax.devices()
+    got = {{
+        "gemm-1": run("gemm"),
+        "gemm-mesh2": run("gemm", mesh=2, placement={{"Collector1": 1}}),
+        "gemm-mesh4": run("gemm", mesh=4, placement={{"Collector0": 3,
+                                                       "Collector1": 1}}),
+        # a mesh whose device order is not the shards' row order
+        "gemm-mesh4-reversed": run(
+            "gemm", mesh=Mesh(np.asarray(devs[:4][::-1]), ("dev",)),
+            placement={{"Collector0": 2, "Collector1": 1}}),
+        "page_rank-1": run("page_rank"),
+        "page_rank-mesh2": run("page_rank", mesh=2),
+    }}
+    print(json.dumps(got))
+""")
+
+PINS = {"gemm-mesh2": {"Collector1": 1},
+        "gemm-mesh4": {"Collector0": 3, "Collector1": 1},
+        "gemm-mesh4-reversed": {"Collector0": 2, "Collector1": 1},
+        "page_rank-mesh2": {}}
+
+
+@pytest.fixture(scope="module")
+def child_runs(tmp_path_factory):
+    prog = _CHILD.format(src=SRC,
+                         cc=str(tmp_path_factory.mktemp("wb") / "cc"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", sorted(PINS))
+def test_partitioned_writeback_matches_one_device(child_runs, case):
+    """Written mmaps come back byte-identical to the one-device program
+    from the writer's shard (a pinned writer off row 0 would read a row
+    of zeros from the wrong one), read-only mmaps are untouched, and the
+    bytes fetched are the written mmaps' bytes, as on one device."""
+    app = case.split("-")[0]
+    one, got = child_runs[f"{app}-1"], child_runs[case]
+    assert one["ok"] and got["ok"]
+    assert got["out"] == one["out"]
+    assert one["read_only_same"] and got["read_only_same"]
+    assert got["writeback_bytes"] == got["written_nbytes"] \
+        == one["writeback_bytes"] > 0
+    assert got["sweeps"] == one["sweeps"] > 0
+    for task, dev in PINS[case].items():
+        assert got["placed"][task] == dev
+    assert len(set(got["placed"].values())) > 1
+
+
+def test_single_device_writeback_bytes_counts_written_mmaps(tmp_path):
+    top, args, _ = gemm.build_step(P=2, n=4, K=2)
+    eng = repro.ENGINES["compiled"](cache=CompileCache(root=tmp_path))
+    assert eng.writeback_bytes == 0
+    assert eng.run(top, *args).ok
+    assert eng.writeback_bytes == sum(m.data.nbytes for m in args[2])
+
+
+def test_single_device_writeback_bytes_counts_written_ports(tmp_path):
+    """An ``async_mmap`` write port's data comes back like an mmap's."""
+    top, args, check = gemm.build_step_async(P=2, n=4, K=2)
+    eng = repro.ENGINES["compiled"](cache=CompileCache(root=tmp_path))
+    assert eng.run(top, *args).ok and check()[0]
+    _, _, c_ports = args        # A's ports are read-only, B is never written
+    assert eng.writeback_bytes == sum(p.data.nbytes for p in c_ports) > 0
