@@ -69,11 +69,12 @@ timing-dependent), and mmaps both written and read across tasks
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import inspect
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -95,7 +96,7 @@ from ..kernels.dispatch import resolve_impl
 from ..kernels.ring import (RING_CHOICES, RING_ENV, eval_guards, ring_pop,
                             ring_push)
 
-SYNTH_SCHEMA = "synth5"
+SYNTH_SCHEMA = "synth6"
 
 
 def _canon_dtype(dtype: Any) -> np.dtype:
@@ -900,12 +901,20 @@ _P_ACC_R, _P_DEL_R, _P_ACC_W, _P_DEL_W, _P_MAX_R, _P_MAX_W = \
     10, 11, 12, 13, 14, 15
 
 
-def _guard_tables(plan: _Plan):
-    """Static fused-guard tables shared by the single-device and
-    partitioned programs: per (task, phase) read/write token needs over
-    every channel, and the cumulative phase bounds (padded with
-    int32-max so shorter tasks never advance past their last phase).
-    Returns ``(need_r, need_w, bounds_or_None, n_ph_max)``."""
+class _SweepTables(NamedTuple):
+    """The plan as the sweep reads it, as constants: per (task, phase)
+    read/write token needs over every channel, the cumulative phase
+    bounds (padded with int32-max so shorter tasks never advance past
+    their last phase; None when every task has one phase), each
+    channel's capacity and each task's firing total."""
+    need_r: np.ndarray
+    need_w: np.ndarray
+    bounds: Optional[np.ndarray]
+    caps: list
+    totals: np.ndarray
+
+
+def _sweep_tables(plan: _Plan) -> _SweepTables:
     n_tasks = len(plan.tasks)
     n_chans = len(plan.channels)
     n_ph_max = max((len(tp.phases) for tp in plan.tasks), default=1)
@@ -924,7 +933,87 @@ def _guard_tables(plan: _Plan):
         for ti, tp in enumerate(plan.tasks):
             b = tp.bounds[:-1]
             bounds_np[ti, :len(b)] = b
-    return need_r_np, need_w_np, bounds_np, n_ph_max
+    return _SweepTables(
+        need_r=need_r_np, need_w=need_w_np, bounds=bounds_np,
+        caps=[c.capacity for c in plan.channels],
+        totals=np.asarray([tp.total for tp in plan.tasks], np.int32))
+
+
+def _empty_rings(plan: _Plan) -> tuple:
+    """Every channel's ring ``(buf, head, size)``, empty."""
+    return tuple(
+        (jnp.zeros((c.capacity,) + c.shape, _canon_dtype(c.dtype)),
+         jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+        for c in plan.channels)
+
+
+def _ring_sizes(chans) -> jax.Array:
+    """The rings' sizes as one vector (a zero where there are none)."""
+    return (jnp.stack([c[2] for c in chans]) if chans
+            else jnp.zeros((1,), jnp.int32))
+
+
+def _sweep_phases(tables: _SweepTables, fires, totals_v) -> tuple:
+    """Each task's phase at sweep start (the phase bounds its firing
+    count has passed) and whether it has firings left."""
+    if tables.bounds is not None:
+        phase_vec = jnp.sum(
+            (fires[:, None] >= jnp.asarray(tables.bounds))
+            .astype(jnp.int32), axis=1)
+    else:
+        phase_vec = jnp.zeros((fires.shape[0],), jnp.int32)
+    return phase_vec, fires < totals_v
+
+
+def _sweep_guards(tables: _SweepTables, chans, phase_vec, live,
+                  ring_impl: str) -> tuple:
+    """Firing guards, evaluated *fused at sweep start*: one
+    :func:`repro.kernels.ring.eval_guards` call computes every task's
+    fire predicate from the ring sizes.  This is sound — and
+    stall-for-stall equivalent to sequential mid-sweep guards — because
+    each channel has one producer and one consumer: a consumer's
+    available tokens can only shrink through its own firing, and a
+    producer's free space only through its own, so a guard true at sweep
+    start is still true when the task's effects apply in task order.
+    Returns ``(fire_vec, sizes, nr, nw)``: the sizes read and each task's
+    current-phase needs per channel (None without channels)."""
+    if not chans:
+        return live, None, None, None
+    sizes = _ring_sizes(chans)
+    nr = jnp.take_along_axis(
+        jnp.asarray(tables.need_r), phase_vec[:, None, None],
+        axis=1)[:, 0, :]
+    nw = jnp.take_along_axis(
+        jnp.asarray(tables.need_w), phase_vec[:, None, None],
+        axis=1)[:, 0, :]
+    fire_vec = eval_guards(sizes, jnp.asarray(tables.caps, jnp.int32), nr,
+                           nw, live, impl=ring_impl)
+    return fire_vec, sizes, nr, nw
+
+
+def _fire_tasks(plan: _Plan, fire_vec, phase_vec, chans: list,
+                states: list, mmaps: list,
+                owns: Optional[Callable] = None):
+    """Every task's guarded firing, in plan order: a ``lax.cond`` on its
+    fire predicate (and on ``owns(ti)``, where a mesh device fires only
+    its own tasks) over its current phase, whose state, rings and mmaps
+    replace the task's entries of ``states``, ``chans`` and ``mmaps`` in
+    place.  Yields each task's plan once its firing has landed."""
+    for ti, tp in enumerate(plan.tasks):
+        fire = fire_vec[ti] if owns is None else fire_vec[ti] & owns(ti)
+        probes = [_phase_probe(plan, tp, ph.fn, rec=None)
+                  for ph in tp.phases]
+        fire_fn = probes[0] if len(probes) == 1 else functools.partial(
+            jax.lax.switch, phase_vec[ti], probes)
+        new_sub = jax.lax.cond(fire, fire_fn, lambda *s: s, states[ti],
+                               tuple(chans[ci] for ci in tp.chan_ids),
+                               tuple(mmaps[mi] for mi in tp.mmap_ids))
+        states[ti] = new_sub[0]
+        for k, ci in enumerate(tp.chan_ids):
+            chans[ci] = new_sub[1][k]
+        for k, mi in enumerate(tp.mmap_ids):
+            mmaps[mi] = new_sub[2][k]
+        yield tp
 
 
 def _rebase_port_dues(pc: tuple, sweeps) -> tuple:
@@ -947,20 +1036,12 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
 
     carry = (chans, states, mmaps, ports, fires, progress, sweeps,
     maxocc); one while_loop iteration is one *sweep*: every task instance
-    gets one guarded chance to fire, then every async port gets one
-    service step.  The loop runs until every task exhausted its firing
-    budget and every port drained its in-flight window, or a full sweep
-    made no progress (the compiled analogue of the engines' deadlock
-    detection).
-
-    Firing guards are evaluated *fused at sweep start*: one
-    :func:`repro.kernels.ring.eval_guards` call computes every task's
-    fire predicate from the occupancy vector.  This is sound — and
-    stall-for-stall equivalent to the old sequential mid-sweep guards —
-    because each channel has one producer and one consumer: a consumer's
-    available tokens can only shrink through its own firing, and a
-    producer's free space only through its own, so a guard true at sweep
-    start is still true when the task's effects apply in task order.
+    gets one guarded chance to fire (:func:`_sweep_guards`,
+    :func:`_fire_tasks`), then every async port gets one service step.
+    Each channel's occupancy highwater is sampled after every firing.
+    The loop runs until every task exhausted its firing budget and every
+    port drained its in-flight window, or a full sweep made no progress
+    (the compiled analogue of the engines' deadlock detection).
 
     Each async port is a fixed-``depth`` latency queue: the service step
     accepts queued requests issue-ahead (up to ``depth`` outstanding per
@@ -985,12 +1066,8 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
     exactly.  Both variants trace the identical sweep body, so a chunked
     resumable run lands on the same fires — and therefore bit-identical
     channel/mmap/port contents — as one uninterrupted program."""
-    caps = [c.capacity for c in plan.channels]
-    totals = np.asarray([tp.total for tp in plan.tasks], np.int32)
-    n_chans = len(plan.channels)
-    n_tasks = len(plan.tasks)
-    ring_impl = plan.ring_impl
-    need_r_np, need_w_np, bounds_np, n_ph_max = _guard_tables(plan)
+    tables = _sweep_tables(plan)
+    caps = tables.caps
 
     def _service_ports(chans, ports, sweeps):
         """One per-sweep service step for every port: deliver due
@@ -1096,8 +1173,8 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
         return chans, tuple(ports), activity, waiting
 
     def _run_loop(chans0, states0, mmaps0, ports0, fires0, budget):
-        totals_v = jnp.asarray(totals)
-        maxocc0 = jnp.zeros((max(n_chans, 1),), jnp.int32)
+        totals_v = jnp.asarray(tables.totals)
+        maxocc0 = jnp.zeros((max(len(caps), 1),), jnp.int32)
 
         def cond(carry):
             _, _, _, ports, fires, progress, sweeps, _ = carry
@@ -1114,49 +1191,11 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
             chans = list(chans)
             states = list(states)
             mmaps = list(mmaps)
-            # fused start-of-sweep guard evaluation: one kernel for every
-            # task's fire predicate
-            if n_ph_max > 1:
-                phase_vec = jnp.sum(
-                    (fires[:, None] >= jnp.asarray(bounds_np))
-                    .astype(jnp.int32), axis=1)
-            else:
-                phase_vec = jnp.zeros((n_tasks,), jnp.int32)
-            live = fires < totals_v
-            if n_chans:
-                sizes_vec = jnp.stack([c[2] for c in chans])
-                nr = jnp.take_along_axis(
-                    jnp.asarray(need_r_np), phase_vec[:, None, None],
-                    axis=1)[:, 0, :]
-                nw = jnp.take_along_axis(
-                    jnp.asarray(need_w_np), phase_vec[:, None, None],
-                    axis=1)[:, 0, :]
-                fire_vec = eval_guards(
-                    sizes_vec, jnp.asarray(caps, jnp.int32), nr, nw, live,
-                    impl=ring_impl)
-            else:
-                fire_vec = live
-            for ti, tp in enumerate(plan.tasks):
-                fire = fire_vec[ti]
-                phase = phase_vec[ti] if len(tp.phases) > 1 else None
-
-                branches = [
-                    _fire_branch(plan, tp, ph.fn) for ph in tp.phases]
-
-                def fire_fn(sub, branches=branches, phase=phase):
-                    if len(branches) == 1:
-                        return branches[0](sub)
-                    return jax.lax.switch(phase, branches, sub)
-
-                sub = (states[ti],
-                       tuple(chans[ci] for ci in tp.chan_ids),
-                       tuple(mmaps[mi] for mi in tp.mmap_ids))
-                new_sub = jax.lax.cond(fire, fire_fn, lambda s: s, sub)
-                states[ti] = new_sub[0]
-                for k, ci in enumerate(tp.chan_ids):
-                    chans[ci] = new_sub[1][k]
-                for k, mi in enumerate(tp.mmap_ids):
-                    mmaps[mi] = new_sub[2][k]
+            phase_vec, live = _sweep_phases(tables, fires, totals_v)
+            fire_vec = _sweep_guards(tables, chans, phase_vec, live,
+                                     plan.ring_impl)[0]
+            for tp in _fire_tasks(plan, fire_vec, phase_vec, chans, states,
+                                  mmaps):
                 if tp.chan_ids:
                     # occupancy highwater sampled after every firing (a
                     # sweep-boundary sample would always see drained FIFOs)
@@ -1168,8 +1207,7 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
                 chans, ports, activity, waiting = _service_ports(
                     chans, ports, sweeps)
                 progress = fired_any | activity | waiting
-                maxocc = jnp.maximum(
-                    maxocc, jnp.stack([c[2] for c in chans]))
+                maxocc = jnp.maximum(maxocc, _ring_sizes(chans))
             else:
                 progress = fired_any
             return (tuple(chans), tuple(states), tuple(mmaps), ports,
@@ -1189,41 +1227,28 @@ def _build_program(plan: _Plan, resumable: bool = False) -> Callable:
                             jnp.asarray(fires0, jnp.int32),
                             jnp.asarray(max_sweeps, jnp.int32))
             ports = tuple(_rebase_port_dues(p, sweeps) for p in ports)
-            sizes = (jnp.stack([c[2] for c in chans]) if n_chans
-                     else jnp.zeros((1,), jnp.int32))
             return (tuple(chans), tuple(states), tuple(mmaps), ports,
-                    fires, progress, sweeps, maxocc, sizes)
+                    fires, progress, sweeps, maxocc, _ring_sizes(chans))
     else:
         def program(states0: tuple, mmaps0: tuple, ports0: tuple):
-            chans0 = tuple(
-                (jnp.zeros((c.capacity,) + c.shape, _canon_dtype(c.dtype)),
-                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-                for c in plan.channels)
+            chans0 = _empty_rings(plan)
             fires0 = jnp.zeros((len(plan.tasks),), jnp.int32)
             chans, states, mmaps, ports, fires, _, sweeps, maxocc = \
                 _run_loop(chans0, states0, mmaps0, ports0, fires0, None)
-            sizes = (jnp.stack([c[2] for c in chans]) if n_chans
-                     else jnp.zeros((max(n_chans, 1),), jnp.int32))
-            return tuple(mmaps), ports, fires, sweeps, maxocc, sizes
+            return (tuple(mmaps), ports, fires, sweeps, maxocc,
+                    _ring_sizes(chans))
 
     return program
-
-
-def _fire_branch(plan: _Plan, tp: _TaskPlan, fn: Callable) -> Callable:
-    probe = _phase_probe(plan, tp, fn, rec=None)
-
-    def branch(sub):
-        state, chs, mms = sub
-        return probe(state, chs, mms)
-
-    return branch
 
 
 def _build_partitioned_program(plan: _Plan, owners, mesh,
                                axis: str = "dev") -> Callable:
     """The multi-device twin of :func:`_build_program`: one
-    ``shard_map`` whose per-device body runs the whole-graph while_loop,
-    firing only the tasks ``owners`` assigns to that device.
+    ``shard_map`` whose per-device body runs the same sweep, firing only
+    the tasks ``owners`` assigns to that device (the ownership predicate
+    of :func:`_fire_tasks`), and ends each sweep with the head/size
+    resynchronisation and the cut channels' exchange below.  A plan
+    with async ports is refused before this is built.
 
     The partition invariant is *sweep-synchronous SPMD*: at every sweep
     start, all devices agree on every channel's head/size and every
@@ -1253,8 +1278,9 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
     Under this invariant the partitioned run executes the identical
     firing schedule, pops the identical values and writes the identical
     mmap cells as the single-device lowering — bit-identical outputs.
-    (Channel ``max_occupancy`` becomes sweep-granular: sampled from
-    sweep-end sizes rather than after every firing.)
+    Channel ``max_occupancy`` is sweep-granular here, sampled from the
+    resynchronised sweep-end sizes: mid-sweep, a device's ring sizes
+    reflect only its own tasks' firings.
 
     Outputs are stacked across the mesh axis (every leaf gains a
     leading device dimension); the caller fetches only the authoritative
@@ -1262,42 +1288,24 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
     the replicated fires/sweeps/maxocc/sizes — and never an unwritten
     mmap.
     """
-    if plan.ports:
-        raise SynthesisError(
-            "partitioned lowering does not cover async_mmap ports")
-    caps = [c.capacity for c in plan.channels]
-    totals = np.asarray([tp.total for tp in plan.tasks], np.int32)
+    from .floorplan import channel_endpoints
+    tables = _sweep_tables(plan)
     n_chans = len(plan.channels)
-    n_tasks = len(plan.tasks)
-    ring_impl = plan.ring_impl
-    need_r_np, need_w_np, bounds_np, n_ph_max = _guard_tables(plan)
     owners_np = np.asarray(owners, np.int32)
-    caps_np = np.asarray(caps, np.int32) if n_chans else \
+    caps_np = np.asarray(tables.caps, np.int32) if n_chans else \
         np.zeros((1,), np.int32)
     # cut edges: (channel, producer device, consumer device)
-    prod = [-1] * n_chans
-    cons = [-1] * n_chans
-    for ti, tp in enumerate(plan.tasks):
-        for ph in tp.phases:
-            for ci in ph.writes:
-                prod[ci] = ti
-            for ci in ph.reads:
-                cons[ci] = ti
-    cuts = [(ci, int(owners_np[prod[ci]]), int(owners_np[cons[ci]]))
-            for ci in range(n_chans)
-            if prod[ci] >= 0 and cons[ci] >= 0
-            and owners_np[prod[ci]] != owners_np[cons[ci]]]
+    cuts = [(ci, int(owners_np[p]), int(owners_np[c]))
+            for ci, (p, c) in enumerate(channel_endpoints(plan))
+            if p >= 0 and c >= 0 and owners_np[p] != owners_np[c]]
 
     def device_body(states0, mmaps0):
         me = jax.lax.axis_index(axis)
         owners_v = jnp.asarray(owners_np)
-        totals_v = jnp.asarray(totals)
+        totals_v = jnp.asarray(tables.totals)
         caps_v = jnp.asarray(caps_np)
-        chans0 = tuple(
-            (jnp.zeros((c.capacity,) + c.shape, _canon_dtype(c.dtype)),
-             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-            for c in plan.channels)
-        fires0 = jnp.zeros((n_tasks,), jnp.int32)
+        chans0 = _empty_rings(plan)
+        fires0 = jnp.zeros((len(plan.tasks),), jnp.int32)
         maxocc0 = jnp.zeros((max(n_chans, 1),), jnp.int32)
 
         def cond(carry):
@@ -1309,48 +1317,14 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
             chans = list(chans)
             states = list(states)
             mmaps = list(mmaps)
-            if n_ph_max > 1:
-                phase_vec = jnp.sum(
-                    (fires[:, None] >= jnp.asarray(bounds_np))
-                    .astype(jnp.int32), axis=1)
-            else:
-                phase_vec = jnp.zeros((n_tasks,), jnp.int32)
-            live = fires < totals_v
+            phase_vec, live = _sweep_phases(tables, fires, totals_v)
             if n_chans:
                 heads0 = jnp.stack([c[1] for c in chans])
-                sizes0 = jnp.stack([c[2] for c in chans])
-                nr = jnp.take_along_axis(
-                    jnp.asarray(need_r_np), phase_vec[:, None, None],
-                    axis=1)[:, 0, :]
-                nw = jnp.take_along_axis(
-                    jnp.asarray(need_w_np), phase_vec[:, None, None],
-                    axis=1)[:, 0, :]
-                fire_vec = eval_guards(
-                    sizes0, jnp.asarray(caps, jnp.int32), nr, nw, live,
-                    impl=ring_impl)
-            else:
-                fire_vec = live
-            for ti, tp in enumerate(plan.tasks):
-                fire = fire_vec[ti] & (owners_v[ti] == me)
-                phase = phase_vec[ti] if len(tp.phases) > 1 else None
-
-                branches = [
-                    _fire_branch(plan, tp, ph.fn) for ph in tp.phases]
-
-                def fire_fn(sub, branches=branches, phase=phase):
-                    if len(branches) == 1:
-                        return branches[0](sub)
-                    return jax.lax.switch(phase, branches, sub)
-
-                sub = (states[ti],
-                       tuple(chans[ci] for ci in tp.chan_ids),
-                       tuple(mmaps[mi] for mi in tp.mmap_ids))
-                new_sub = jax.lax.cond(fire, fire_fn, lambda s: s, sub)
-                states[ti] = new_sub[0]
-                for k, ci in enumerate(tp.chan_ids):
-                    chans[ci] = new_sub[1][k]
-                for k, mi in enumerate(tp.mmap_ids):
-                    mmaps[mi] = new_sub[2][k]
+            fire_vec, sizes0, nr, nw = _sweep_guards(
+                tables, chans, phase_vec, live, plan.ring_impl)
+            for _ in _fire_tasks(plan, fire_vec, phase_vec, chans, states,
+                                 mmaps, owns=lambda ti: owners_v[ti] == me):
+                pass
             if n_chans:
                 fv = fire_vec.astype(jnp.int32)
                 delta_r = jnp.sum(fv[:, None] * nr, axis=0)
@@ -1374,9 +1348,7 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
                   maxocc0)
         chans, states, mmaps, fires, _, sweeps, maxocc = \
             jax.lax.while_loop(cond, body, carry0)
-        sizes = (jnp.stack([c[2] for c in chans]) if n_chans
-                 else jnp.zeros((max(n_chans, 1),), jnp.int32))
-        out = (tuple(mmaps), fires, sweeps, maxocc, sizes)
+        out = (tuple(mmaps), fires, sweeps, maxocc, _ring_sizes(chans))
         # every leaf gains a leading device axis; the concatenated
         # global view lets the host pick the authoritative row
         return jax.tree.map(lambda x: jnp.asarray(x)[None], out)
@@ -1389,6 +1361,13 @@ def _build_partitioned_program(plan: _Plan, owners, mesh,
                              check_vma=False)(states0, mmaps0)
 
     return program
+
+
+def _mmap_writers(plan: _Plan) -> dict:
+    """``{mmap index: index of the task that stores to it}``; ``_lower``'s
+    one-writer rule makes the task unique."""
+    return {mi: ti for ti, tp in enumerate(plan.tasks)
+            for ph in tp.phases for mi in ph.mmap_stores}
 
 
 def _row_shard(stacked: jax.Array, row: int) -> jax.Array:
@@ -1674,6 +1653,11 @@ class CompiledEngine(EngineBase):
     def run(self, top: Callable, *args, **kwargs) -> SimReport:
         """Elaborate, lower, key, resolve, copy in, execute, write back.
 
+        On a mesh, :meth:`_partition` places the tasks and lowers the
+        partitioned program after the plan is lowered, and
+        :meth:`_fetch_rows` brings back only the shards the host needs;
+        every other stage is the same on both paths.
+
         Each stage runs under a ``jax.profiler.TraceAnnotation`` named
         ``compiled.<stage>``, nested in one ``compiled.run``, so that a
         profiler trace puts the device's idle time down to a stage; the
@@ -1688,27 +1672,26 @@ class CompiledEngine(EngineBase):
                     plan, graph = self._lower()
                     if self.mesh is None:
                         program = _build_program(plan)
+                owners, extra = None, ""
                 if self.mesh is not None:
-                    return self._run_partitioned(plan, graph, result, t0)
+                    program, owners, extra = self._partition(plan, graph)
                 with _span("compiled.copy_in"):
-                    states0 = tuple(tp.state0 for tp in plan.tasks)
-                    mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
-                    ports0 = tuple(_port_carry0(p) for p in plan.ports)
+                    inputs = (tuple(tp.state0 for tp in plan.tasks),
+                              tuple(jnp.asarray(m.data) for m in plan.mmaps))
+                    if owners is None:
+                        inputs += (
+                            tuple(_port_carry0(p) for p in plan.ports),)
                 with _span("compiled.key"):
-                    key = self._cache_key(plan.structural_hash,
-                                          (states0, mmaps0, ports0),
-                                          plan.ring_impl)
+                    key = self._cache_key(plan.structural_hash, inputs,
+                                          plan.ring_impl, extra)
                 with _span("compiled.resolve"):
-                    exe = self._resolve(program, (states0, mmaps0, ports0),
-                                        key)
+                    exe = self._resolve(program, inputs, key)
                 with _span("compiled.execute"):
-                    out = jax.block_until_ready(exe(states0, mmaps0, ports0))
-                mm_final, ports_final, fires, sweeps, maxocc, sizes = out
+                    out = jax.block_until_ready(exe(*inputs))
                 with _span("compiled.writeback"):
-                    self._writeback_ports(plan, ports_final)
-                    self._fill_port_stats(plan, ports_final)
-                    return self._finish(plan, mm_final, fires, sweeps,
-                                        maxocc, sizes, result, t0)
+                    if owners is not None:
+                        out = self._fetch_rows(plan, owners, out)
+                    return self._finish(plan, *out, result, t0)
             finally:
                 clear_context()
 
@@ -1744,11 +1727,11 @@ class CompiledEngine(EngineBase):
         from ..distributed.sharding import device_mesh
         return device_mesh(int(self.mesh))
 
-    def _run_partitioned(self, plan: _Plan, graph, result,
-                         t0: float) -> SimReport:
-        """The mesh floorplan path: place tasks (cached artifact), lower
-        the partitioned program (cached executable), pick authoritative
-        output rows, and finish exactly like the single-device path."""
+    def _partition(self, plan: _Plan, graph) -> tuple:
+        """The mesh's part of lowering: place the tasks (a placement to
+        reuse, or the floorplanner's) and build the partitioned program.
+        Returns ``(program, owners, extra)``, ``extra`` being the mesh
+        and the owners as the executable's key holds them."""
         from .floorplan import Placement, plan_placement
         mesh = self._resolve_mesh()
         axis = mesh.axis_names[0]
@@ -1783,52 +1766,41 @@ class CompiledEngine(EngineBase):
 
         with _span("compiled.lower"):
             program = _build_partitioned_program(plan, owners, mesh, axis)
-        with _span("compiled.copy_in"):
-            states0 = tuple(tp.state0 for tp in plan.tasks)
-            mmaps0 = tuple(jnp.asarray(m.data) for m in plan.mmaps)
-        with _span("compiled.key"):
-            key = self._cache_key(
-                plan.structural_hash, (states0, mmaps0), plan.ring_impl,
-                extra=f"mesh={axis}:{n_dev}:owners={owners.tolist()}")
-        with _span("compiled.resolve"):
-            exe = self._resolve(program, (states0, mmaps0), key)
-        with _span("compiled.execute"):
-            mm_st, fires_st, sweeps_st, maxocc_st, sizes_st = \
-                jax.block_until_ready(exe(states0, mmaps0))
-        with _span("compiled.writeback"):
-            # authoritative rows: the writer's owner per written mmap (the
-            # one-writer rule makes it unique); anything replicated -> row
-            # 0.  Only those shards leave the device, in one device_get so
-            # the chips' transfers overlap; _writeback reads no unwritten
-            # mmap, so those stay on the device
-            writer_of = {}
-            for ti, tp in enumerate(plan.tasks):
-                for ph in tp.phases:
-                    for mi in ph.mmap_stores:
-                        writer_of[mi] = int(owners[ti])
-            written = sorted(writer_of)
-            rows = jax.device_get(
-                [_row_shard(mm_st[mi], writer_of[mi]) for mi in written]
-                + [_row_shard(x, 0)
-                   for x in (fires_st, sweeps_st, maxocc_st, sizes_st)])
-            rows = [r[0] for r in rows]
-            mm_final = [None] * len(mm_st)
-            for mi, row in zip(written, rows):
-                mm_final[mi] = row
-                self.writeback_bytes += row.nbytes
-            fires, sweeps, maxocc, sizes = rows[len(written):]
-            return self._finish(plan, tuple(mm_final), fires, sweeps,
-                                maxocc, sizes, result, t0)
+        return program, owners, \
+            f"mesh={axis}:{n_dev}:owners={owners.tolist()}"
 
-    def _finish(self, plan: _Plan, mm_final, fires, sweeps, maxocc,
-                sizes, result, t0: float) -> SimReport:
-        """Shared back half of a compiled run: write mmaps back to host,
-        fill stats, diagnose stalls, build the report."""
+    def _fetch_rows(self, plan: _Plan, owners, out) -> tuple:
+        """The partitioned program's results on the host, from outputs
+        stacked over the mesh: the writer's owner's row of each written
+        mmap (``None`` for the rest, which stay on the device and which
+        ``_writeback`` never reads) and row 0 of the replicated fires,
+        sweeps, maxocc and sizes, in one ``jax.device_get`` so that the
+        chips' transfers overlap.  Shaped as the one-device program's
+        outputs, with no ports."""
+        mm_st, *replicated = out
+        writer_of = {mi: int(owners[ti])
+                     for mi, ti in _mmap_writers(plan).items()}
+        written = sorted(writer_of)
+        rows = jax.device_get(
+            [_row_shard(mm_st[mi], writer_of[mi]) for mi in written]
+            + [_row_shard(x, 0) for x in replicated])
+        rows = [r[0] for r in rows]
+        mm_final = [None] * len(mm_st)
+        for mi, row in zip(written, rows):
+            mm_final[mi] = row
+            self.writeback_bytes += row.nbytes
+        return (tuple(mm_final), (), *rows[len(written):])
+
+    def _finish(self, plan: _Plan, mm_final, ports_final, fires, sweeps,
+                maxocc, sizes, result, t0: float) -> SimReport:
+        """Shared back half of a compiled run: write mmaps and ports back
+        to host, fill stats, diagnose stalls, build the report."""
+        self._fill_port_stats(plan, ports_final)
         fires = np.asarray(fires)
         maxocc = np.asarray(maxocc)
         sizes = np.asarray(sizes)
         self.n_sweeps = self.switches = int(sweeps)
-        self._writeback(plan, mm_final)
+        self._writeback(plan, mm_final, ports_final)
         self._fill_stats(plan, fires, maxocc)
         totals = np.asarray([tp.total for tp in plan.tasks], np.int32)
         stuck = bool(np.any(fires < totals))
@@ -1854,39 +1826,27 @@ class CompiledEngine(EngineBase):
         return self._report(not stuck, time.perf_counter() - t0, err,
                             result)
 
-    def _writeback(self, plan: _Plan, mm_final: tuple) -> None:
-        """Copy device results back into the host mmap buffers, so the
-        same ``check()`` that verifies a simulation run verifies the
-        compiled run."""
-        written = set()
-        for tp in plan.tasks:
-            for ph in tp.phases:
-                written.update(ph.mmap_stores)
-        for mi in sorted(written):
-            m = plan.mmaps[mi]
-            out = self._to_host(mm_final[mi])
-            if isinstance(m.data, np.ndarray):
-                np.copyto(m.data, out)
-            else:
-                m.data = out
+    def _writeback(self, plan: _Plan, mm_final: tuple,
+                   ports_final: tuple) -> None:
+        """Copy device results back into the host buffers of the written
+        mmaps and ports, so the same ``check()`` that verifies a
+        simulation run verifies the compiled run."""
+        for mi in sorted(_mmap_writers(plan)):
+            self._copy_back(plan.mmaps[mi], mm_final[mi])
+        for p, dirs, pc in zip(plan.ports, plan.port_dirs, ports_final):
+            if "write" in dirs:
+                self._copy_back(p, pc[_P_DATA])
 
-    def _to_host(self, x) -> np.ndarray:
-        """``x`` as a host array; a device array's bytes count in
-        ``writeback_bytes``."""
+    def _copy_back(self, target, x) -> None:
+        """``x`` into ``target.data``, in place where that is a host
+        array; a device array's bytes count in ``writeback_bytes``."""
         out = np.asarray(x)
         if isinstance(x, jax.Array):
             self.writeback_bytes += out.nbytes
-        return out
-
-    def _writeback_ports(self, plan: _Plan, ports_final: tuple) -> None:
-        for pi, (p, pc) in enumerate(zip(plan.ports, ports_final)):
-            if "write" not in plan.port_dirs[pi]:
-                continue
-            out = self._to_host(pc[_P_DATA])
-            if isinstance(p.data, np.ndarray):
-                np.copyto(p.data, out)
-            else:
-                p.data = out
+        if isinstance(target.data, np.ndarray):
+            np.copyto(target.data, out)
+        else:
+            target.data = out
 
     def _fill_port_stats(self, plan: _Plan, ports_final: tuple) -> None:
         """Fill each port's always-on request counters from the compiled

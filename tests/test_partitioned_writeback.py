@@ -4,12 +4,16 @@ The partitioned program's outputs are stacked over the mesh, one row per
 device; ``CompiledEngine`` fetches only the writer's shard of each written
 mmap and one row of the replicated counters, and leaves read-only mmaps on
 the device.  ``writeback_bytes`` counts what came back.  These tests hold
-the partitioned runs byte-identical to the one-device program and the
-counter to the written mmaps' bytes, on either path.
+the partitioned runs byte-identical to the one-device program, with the
+same firings and channel token counts (both programs run one sweep), and
+the counter to the written mmaps' bytes, on either path.  Channel
+``max_occupancy`` is the one count that differs: on a mesh it is sampled
+at sweep ends.
 """
 
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -37,9 +41,39 @@ _CHILD = textwrap.dedent("""
     from jax.sharding import Mesh
     import repro
     from repro.apps import gemm, page_rank
+    from repro.core import synth
     from repro.core.compile_cache import CompileCache
+    from repro.ft.recovery import _abstract_schedule
 
     cc = CompileCache(root={cc!r})
+
+    # the plan and the per-task firing counts each run hands its stats
+    seen = {{}}
+    fill_stats = synth.CompiledEngine._fill_stats
+
+    def spy(self, plan, fires, maxocc):
+        seen.update(plan=plan, fires=[int(f) for f in fires])
+        return fill_stats(self, plan, fires, maxocc)
+
+    synth.CompiledEngine._fill_stats = spy
+
+    # each channel's largest size at a sweep boundary of the abstract
+    # schedule, whose cuts are the fires after each sweep
+    def sweep_end_max(plan):
+        best = [0] * len(plan.channels)
+        for cut in _abstract_schedule(plan)[0]:
+            size = [0] * len(plan.channels)
+            for tp, f in zip(plan.tasks, cut):
+                start = 0
+                for ph in tp.phases:
+                    k = min(max(f - start, 0), ph.count)
+                    start += ph.count
+                    for ci, w in ph.writes.items():
+                        size[ci] += w * k
+                    for ci, r in ph.reads.items():
+                        size[ci] -= r * k
+            best = [max(b, n) for b, n in zip(best, size)]
+        return best
 
     def build(app):
         if app == "gemm":
@@ -59,7 +93,15 @@ _CHILD = textwrap.dedent("""
         if eng.placement_used is not None:
             placed = dict(zip(eng.placement_used.task_names,
                               map(int, eng.placement_used.owners)))
+        plan = seen["plan"]
         return {{
+            "tasks": [tp.inst.name for tp in plan.tasks],
+            "fires": seen["fires"],
+            "channels": [c.name for c in plan.channels],
+            "totals": [[c.total_read, c.total_written]
+                       for c in plan.channels],
+            "maxocc": [c.max_occupancy for c in plan.channels],
+            "sweep_end_max": sweep_end_max(plan),
             "ok": bool(rep.ok and check()[0]),
             "out": b"".join(np.asarray(m.data).tobytes()
                             for m in written).hex(),
@@ -94,6 +136,13 @@ PINS = {"gemm-mesh2": {"Collector1": 1},
         "page_rank-mesh2": {}}
 
 
+def _plan_order(run: dict) -> tuple:
+    """A run's tasks and channels in plan order, by name less the
+    instance number some names carry (``Ctrl#51``)."""
+    return tuple([re.sub(r"#\d+$", "", n) for n in run[k]]
+                 for k in ("tasks", "channels"))
+
+
 @pytest.fixture(scope="module")
 def child_runs(tmp_path_factory):
     prog = _CHILD.format(src=SRC,
@@ -120,9 +169,32 @@ def test_partitioned_writeback_matches_one_device(child_runs, case):
     assert got["writeback_bytes"] == got["written_nbytes"] \
         == one["writeback_bytes"] > 0
     assert got["sweeps"] == one["sweeps"] > 0
+    # one sweep on both paths: the same firings, the same tokens on every
+    # channel, in the same plan order
+    assert _plan_order(got) == _plan_order(one)
+    assert got["fires"] == one["fires"] and sum(one["fires"]) > 0
+    assert got["totals"] == one["totals"]
     for task, dev in PINS[case].items():
         assert got["placed"][task] == dev
     assert len(set(got["placed"].values())) > 1
+
+
+@pytest.mark.parametrize("case", sorted(PINS))
+def test_partitioned_max_occupancy_is_sweep_granular(child_runs, case):
+    """A mesh samples each channel's occupancy at sweep end, after the
+    sizes are resynchronised: never above the one-device highwater, which
+    is sampled after every firing, and equal to the largest sweep-end
+    size of the abstract schedule."""
+    app = case.split("-")[0]
+    one, got = child_runs[f"{app}-1"], child_runs[case]
+    assert _plan_order(got) == _plan_order(one)
+    for name, mesh, single, boundary in zip(
+            got["channels"], got["maxocc"], one["maxocc"],
+            got["sweep_end_max"]):
+        assert mesh <= single, name
+        assert mesh == boundary, name
+    assert got["sweep_end_max"] == one["sweep_end_max"]
+    assert max(got["maxocc"]) > 0
 
 
 def test_single_device_writeback_bytes_counts_written_mmaps(tmp_path):
