@@ -176,6 +176,7 @@ def plan_placement(plan, graph, n_devices: int, *,
     _validate_overrides(names, overrides, n_devices)
 
     cc = default_cache() if cache is None else (cache or None)
+    # the hash lowering already stored on this Graph: no second pass
     key = placement_key(graph.structural_hash(), n_devices, overrides, hw)
     if cc is not None:
         hit = cc.memo_get(key)

@@ -116,6 +116,25 @@ class Graph:
     interfaces: list = field(default_factory=list)   # MMap/AsyncMMap objects
     report: Optional[SimReport] = None
     _defs: dict = field(default_factory=dict, repr=False)
+    # each task definition's structural digest, computed at most once and
+    # read by both ``definitions`` and ``structural_hash`` (ids are stable
+    # while self.instances pins the fn objects).  It lives and dies with
+    # this Graph: a compiled run extracts a new one per invocation, so a
+    # captured array edited in place between invocations is hashed anew.
+    _digests: dict = field(default_factory=dict, repr=False)
+    _hash: Optional[str] = field(default=None, repr=False)
+
+    def _digest(self, fn: Callable) -> str:
+        d = self._digests.get(id(fn))
+        if d is None:
+            d = self._digests[id(fn)] = structural_digest(fn)
+        return d
+
+    @property
+    def n_digests(self) -> int:
+        """Definition digests this graph has computed: one per task
+        definition object, however many passes read them."""
+        return len(self._digests)
 
     # ------------------------------------------------------------------
     @property
@@ -129,15 +148,8 @@ class Graph:
         """
         if not self._defs:
             by_hash: dict[str, list[TaskInstance]] = {}
-            # per-sweep digest memo: N instances of K definitions need K
-            # content hashes (ids are stable while self.instances pins
-            # the fn objects)
-            digests: dict = {}
             for i in self.instances:
-                d = digests.get(id(i.fn))
-                if d is None:
-                    d = digests[id(i.fn)] = structural_digest(i.fn)
-                by_hash.setdefault(d, []).append(i)
+                by_hash.setdefault(self._digest(i.fn), []).append(i)
             self._defs = {
                 h: DefinitionInfo(
                     fn=insts[0].fn,
@@ -192,11 +204,13 @@ class Graph:
         for the same input avals": mmap buffer *values* and instance/
         channel *names* are excluded, so N graphs over N datasets share
         one whole-graph compile (the key ``repro.core.synth`` caches on).
+        Computed once per Graph, like ``definitions``.
         """
+        if self._hash is not None:
+            return self._hash
         chan_idx = {id(c): i for i, c in enumerate(self.channels)}
         iface_idx = {id(m): i for i, m in enumerate(self.interfaces)}
         inst_idx = {id(i): n for n, i in enumerate(self.instances)}
-        digests: dict[int, str] = {}
         h = hashlib.sha256()
 
         def enc_arg(v: Any) -> None:
@@ -229,11 +243,8 @@ class Graph:
                 _enc(h, v)
 
         for inst in self.instances:
-            d = digests.get(id(inst.fn))
-            if d is None:
-                d = digests[id(inst.fn)] = structural_digest(inst.fn)
             h.update(b"inst")
-            h.update(d.encode())
+            h.update(self._digest(inst.fn).encode())
             h.update(f"p{inst_idx.get(id(inst.parent), -1)}"
                      f"d{int(inst.detach)}".encode())
             for a in inst.args:
@@ -241,7 +252,8 @@ class Graph:
             for k in sorted(inst.kwargs):
                 h.update(k.encode())
                 enc_arg(inst.kwargs[k])
-        return h.hexdigest()
+        self._hash = h.hexdigest()
+        return self._hash
 
     # ------------------------------------------------------------------
     def validate(self) -> None:

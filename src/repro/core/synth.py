@@ -1425,6 +1425,7 @@ class CompiledEngine(EngineBase):
         self.writeback_bytes = 0        # mmap/port results fetched to host
         self.n_phase_traces = 0         # _lower's jax.eval_shape traces
         self.lower_source = None        # "traced" | "memory" | None
+        self.n_digests = 0              # definition digests of the last run
         self.placement_used = None      # floorplan.Placement after a run
         self.partition_source = None    # "partitioned" | "memo" | None
 
@@ -1675,6 +1676,7 @@ class CompiledEngine(EngineBase):
                 owners, extra = None, ""
                 if self.mesh is not None:
                     program, owners, extra = self._partition(plan, graph)
+                self.n_digests = graph.n_digests
                 with _span("compiled.copy_in"):
                     inputs = (tuple(tp.state0 for tp in plan.tasks),
                               tuple(jnp.asarray(m.data) for m in plan.mmaps))

@@ -41,7 +41,7 @@ _CHILD = textwrap.dedent("""
     from jax.sharding import Mesh
     import repro
     from repro.apps import gemm, page_rank
-    from repro.core import synth
+    from repro.core import graph as graph_mod, synth
     from repro.core.compile_cache import CompileCache
     from repro.ft.recovery import _abstract_schedule
 
@@ -56,6 +56,16 @@ _CHILD = textwrap.dedent("""
         return fill_stats(self, plan, fires, maxocc)
 
     synth.CompiledEngine._fill_stats = spy
+
+    # every definition digest a Graph computes
+    digests = []
+    structural_digest = graph_mod.structural_digest
+
+    def counting(fn):
+        digests.append(fn)
+        return structural_digest(fn)
+
+    graph_mod.structural_digest = counting
 
     # each channel's largest size at a sweep boundary of the abstract
     # schedule, whose cuts are the fires after each sweep
@@ -88,7 +98,9 @@ _CHILD = textwrap.dedent("""
         top, args, check, written, read_only = build(app)
         before = [np.array(m.data, copy=True) for m in read_only]
         eng = repro.ENGINES["compiled"](cache=cc, **kw)
+        digests.clear()
         rep = eng.run(top, *args)
+        n_digest_calls = len(digests)
         placed = {{}}
         if eng.placement_used is not None:
             placed = dict(zip(eng.placement_used.task_names,
@@ -112,7 +124,15 @@ _CHILD = textwrap.dedent("""
                                       for m in written)),
             "writeback_bytes": int(eng.writeback_bytes),
             "sweeps": int(eng.n_sweeps),
-            "placed": placed}}
+            "placed": placed,
+            "partition_source": eng.partition_source,
+            "n_digests": eng.n_digests,
+            "digest_calls": n_digest_calls,
+            "definitions": len(definitions(app))}}
+
+    def definitions(app):
+        top, args = build(app)[:2]
+        return synth.elaborate_step_graph(top, *args)[1].definitions
 
     devs = jax.devices()
     got = {{
@@ -120,6 +140,9 @@ _CHILD = textwrap.dedent("""
         "gemm-mesh2": run("gemm", mesh=2, placement={{"Collector1": 1}}),
         "gemm-mesh4": run("gemm", mesh=4, placement={{"Collector0": 3,
                                                        "Collector1": 1}}),
+        # the same invocation again: the floorplanner's memo hits
+        "gemm-mesh4-again": run("gemm", mesh=4, placement={{
+            "Collector0": 3, "Collector1": 1}}),
         # a mesh whose device order is not the shards' row order
         "gemm-mesh4-reversed": run(
             "gemm", mesh=Mesh(np.asarray(devs[:4][::-1]), ("dev",)),
@@ -195,6 +218,21 @@ def test_partitioned_max_occupancy_is_sweep_granular(child_runs, case):
         assert mesh == boundary, name
     assert got["sweep_end_max"] == one["sweep_end_max"]
     assert max(got["maxocc"]) > 0
+
+
+@pytest.mark.parametrize("case", sorted(PINS) + ["gemm-mesh4-again"])
+def test_partitioned_run_digests_each_definition_once(child_runs, case):
+    """Lowering, validation and placement read one digest table and one
+    graph hash: a run on a mesh digests each task definition once, as a
+    run on one device does, and the floorplanner's memo hit adds none."""
+    app = case.split("-")[0]
+    one, got = child_runs[f"{app}-1"], child_runs[case]
+    assert got["ok"]
+    assert got["n_digests"] == got["digest_calls"] == got["definitions"] \
+        == one["n_digests"] == one["digest_calls"] > 1
+    if case == "gemm-mesh4-again":
+        assert got["partition_source"] == "memo"
+        assert got["placed"] == child_runs["gemm-mesh4"]["placed"]
 
 
 def test_single_device_writeback_bytes_counts_written_mmaps(tmp_path):
